@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Fault-tolerant serving drain — the queueable (tpu_queue_loop.sh) form
+# Fault-tolerant serving drain — the requeueable form
 # of the daemon cycle, replacing the reference's PBS qsub-requeue
 # workflow (docs/MIGRATION.md): the first pass admits a mixed-shape
 # request burst and drains it through serve.daemon under a write-ahead

@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     tokens = jax.device_put(
         jnp.arange(n, dtype=jnp.int32), NamedSharding(mesh, P("r"))
     )
-    received = mesh_lib.shard_map(
+    received = jax.shard_map(
         lambda t: jax.lax.ppermute(t, "r", ring_perm(n, 1)),
         mesh=mesh, in_specs=P("r"), out_specs=P("r"),
     )(tokens)

@@ -64,6 +64,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "(default 8); excludes --outdir")
     p.add_argument("--outdir", default=None,
                    help="write VTK snapshots here (default: no saves)")
+    p.add_argument("--save-final", action="store_true",
+                   help="also write the final board to --outdir "
+                        "(life_<steps>.vtk), after the timed loop")
     p.add_argument("--times-file", default=None,
                    help="append elapsed seconds to this file (times.txt contract)")
     p.add_argument("--print-final-population", action="store_true")
@@ -262,6 +265,8 @@ def main(argv=None) -> int:
         if args.outdir or args.checkpoint_dir or args.resume:
             parser.error("--batch is a throughput mode: drop --outdir/"
                          "--checkpoint-dir/--resume")
+    if args.save_final and not args.outdir:
+        parser.error("--save-final needs --outdir")
     kwargs = dict(
         layout=args.layout,
         impl=args.impl,
@@ -338,6 +343,7 @@ def main(argv=None) -> int:
                 steps=cfg.steps,
                 impl=sim.impl,
                 layout=sim.layout,
+                plan=getattr(sim, "plan_note", sim.impl),
             ):
                 final = sim.run()  # collect() inside forces completion
         except Preempted as e:
@@ -348,6 +354,8 @@ def main(argv=None) -> int:
         elapsed = time.perf_counter() - t0
     if args.debug_check:
         sim.debug_check()
+    if args.save_final:
+        sim.save_snapshot()
 
     # One process owns stdout and the times file — the reference's
     # print-from-one-rank discipline (3-life/life_mpi.c:64-67).

@@ -44,7 +44,7 @@ class Integral:
         axis = next(iter(self.mesh.shape))
         if self.mesh.size == 1:
             return jax.jit(lambda: quadrature.trapezoid_serial(f, a, b, n))
-        smapped = mesh_lib.shard_map(
+        smapped = jax.shard_map(
             lambda: quadrature.trapezoid_shard_sum(f, a, b, n, axis),
             mesh=self.mesh,
             in_specs=(),

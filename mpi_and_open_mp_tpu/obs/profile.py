@@ -20,9 +20,9 @@ Three instruments, all feeding the PR 4 metrics registry:
 * :func:`roofline` — achieved FLOP/s and bytes/s vs per-device-kind peaks
   (:data:`_PEAKS`; override with ``MOMP_PEAK_FLOPS`` /
   ``MOMP_PEAK_BYTES_S`` when the table's entry is wrong for your part).
-  CPU peaks are NOMINAL order-of-magnitude host numbers — they keep the
-  fraction finite and comparable run-over-run on fallback lines, they do
-  not claim to model the host.
+  A device kind the table does not know is an error, not a default. The
+  CPU row is a NOMINAL order-of-magnitude host number — it keeps the
+  fraction finite on CPU test lines, it does not claim to model the host.
 * :func:`record_memory_gauges` — live-buffer bytes (``jax.live_arrays``),
   a process-lifetime watermark, and per-device ``memory_stats`` bytes in
   use where the backend exposes them, as registry gauges so they ride the
@@ -42,34 +42,36 @@ import time
 
 from mpi_and_open_mp_tpu.obs import metrics
 
-#: (device_kind substring, peak FLOP/s, peak bytes/s). Matched
+#: (device_kind substring, label, peak FLOP/s, peak bytes/s). Matched
 #: case-insensitively in order; first hit wins. TPU rows are bf16 peak +
-#: HBM bandwidth from the public chip specs; the CPU row is a NOMINAL
-#: host-class placeholder (see module docs).
-_PEAKS: tuple[tuple[str, float, float], ...] = (
-    ("v5 lite", 197e12, 819e9),  # v5e ("TPU v5 lite" is the kind string)
-    ("v5e", 197e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v6", 918e12, 1640e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-    ("cpu", 1e11, 2e10),
+#: HBM bandwidth from the public chip specs (Google Cloud TPU docs); the
+#: CPU row is a NOMINAL host-class placeholder (see module docs).
+_PEAKS: tuple[tuple[str, str, float, float], ...] = (
+    # v5e: "TPU v5 lite" is the device_kind string.
+    ("v5 lite", "v5 lite-table", 197e12, 819e9),
+    ("v5e", "v5e-table", 197e12, 819e9),
+    ("v5p", "v5p-table", 459e12, 2765e9),
+    ("v6", "v6-table", 918e12, 1640e9),
+    ("v4", "v4-table", 275e12, 1228e9),
+    ("v3", "v3-table", 123e12, 900e9),
+    ("v2", "v2-table", 45e12, 700e9),
+    ("cpu", "cpu-nominal", 1e11, 2e10),
 )
-_DEFAULT_PEAKS = ("cpu-nominal", 1e11, 2e10)
 
 _COST_CACHE: dict[tuple, dict] = {}
 
 
 def peaks_for(device_kind: str | None) -> tuple[float, float, str]:
     """``(peak_flops_per_sec, peak_bytes_per_sec, label)`` for a device
-    kind, env-overridable per component."""
-    label, flops, bw = _DEFAULT_PEAKS
+    kind, env-overridable per component. Raises ``ValueError`` for a kind
+    the table does not know."""
     kind = (device_kind or "").lower()
-    for sub, f, b in _PEAKS:
+    for sub, label, flops, bw in _PEAKS:
         if sub in kind:
-            label, flops, bw = f"{sub}-table", f, b
             break
+    else:
+        raise ValueError(f"no peak table entry for device kind "
+                         f"{device_kind!r}; add it to obs.profile._PEAKS")
     try:
         flops = float(os.environ.get("MOMP_PEAK_FLOPS", flops))
         bw = float(os.environ.get("MOMP_PEAK_BYTES_S", bw))

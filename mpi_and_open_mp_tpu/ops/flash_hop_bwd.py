@@ -170,7 +170,7 @@ def hop_block_grads(q, do, L128, D128, kb, vb, *, causal: bool,
             f"nk={nk} and be <= {MAX_BLOCK}")
     scale = 1.0 / math.sqrt(d)
     f32 = jnp.float32
-    sem = pltpu.TPUCompilerParams(
+    sem = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     qside = pl.BlockSpec((1, blk, d), lambda ih, ia, ib: (ih, ia, 0))

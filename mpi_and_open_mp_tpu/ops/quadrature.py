@@ -36,8 +36,6 @@ from typing import Callable
 import jax.numpy as jnp
 from jax import lax
 
-from mpi_and_open_mp_tpu.parallel.halo import axis_size
-
 # Grid points evaluated per loop iteration on each device (VPU-friendly).
 CHUNK = 1 << 17
 
@@ -82,7 +80,7 @@ def trapezoid_shard_sum(
     ceil(N/size)`` chunking, ``integral.c:34,49``); returns the
     ``lax.psum``-reduced global integral.
     """
-    p = axis_size(axis_name)  # static: mesh shape known at trace time
+    p = lax.axis_size(axis_name)  # static: mesh shape known at trace time
     k = lax.axis_index(axis_name)
     h = (b - a) / n
     n_chunks, _, _ = _chunk_grid(n)

@@ -44,7 +44,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_and_open_mp_tpu.parallel import mesh as mesh_lib
-from mpi_and_open_mp_tpu.parallel.halo import axis_size, ring_perm
+from mpi_and_open_mp_tpu.parallel.halo import ring_perm
 
 AXIS_SP = "sp"
 
@@ -225,7 +225,7 @@ def _ring_attention_local(q, k, v, *, axis: str, causal: bool,
     per shard — O(seq·d/p) — and the backward re-rotates K/V around the
     ring, recomputing each block from the saved row statistics.
     """
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     if p == 1:
         # A 1-device ring is just full local attention (under EITHER
         # layout: the p=1 zigzag order is the identity); the
@@ -252,7 +252,7 @@ def _ring_forward(axis: str, causal: bool, layout: str, q, k, v):
     device about half a full-block per hop, versus the contiguous split
     where hop wall-clock is set by whichever device's block is
     unskipped (the straggler)."""
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     # TPU-eligible hop shapes take the per-hop Pallas engine instead of
     # the jnp fold below (which remains the oracle and the fallback) —
     # same ring schedule, flash-kernel hops, online-softmax merge.
@@ -491,7 +491,7 @@ def _ring_flash_bwd(axis: str, causal: bool, layout: str, res, do):
     come out group-summed, ``dq`` is unfolded at the end.
     """
     q, k, v, o, L = res
-    p = axis_size(axis)
+    p = lax.axis_size(axis)
     # TPU-eligible hop shapes take the per-hop Pallas backward kernels
     # instead of the jnp fold below (which remains the oracle and the
     # ineligible-shape fallback) — same travelling-dk/dv schedule,
@@ -1646,12 +1646,12 @@ def _traced_rotate_jit(kb, vb, *, mesh: Mesh, axis: str):
     """One K/V ring rotation — the traced ring's transfer step."""
 
     def body(kb, vb):
-        p = axis_size(axis)
+        p = lax.axis_size(axis)
         perm = ring_perm(p, 1)
         return lax.ppermute(kb, axis, perm), lax.ppermute(vb, axis, perm)
 
     spec = _seq_spec(axis)
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
         check_vma=False)(kb, vb)
 
@@ -1667,7 +1667,7 @@ def _traced_fold0_jit(q, kb, vb, *, mesh: Mesh, axis: str, causal: bool,
         return _traced_hop_partial(qs, kb, vb, causal, plan)
 
     spec = _seq_spec(axis)
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=(spec, _traced_L_spec(axis)), check_vma=False)(q, kb, vb)
 
@@ -1690,14 +1690,14 @@ def _traced_fold_jit(o, L, q, kb, vb, j, *, mesh: Mesh, axis: str,
 
         if not causal:
             return take((o, L))
-        p = axis_size(axis)
+        p = lax.axis_size(axis)
         idx = lax.axis_index(axis)
         src = (idx - j) % p
         return lax.cond(src < idx, take, lambda s: s, (o, L))
 
     spec = _seq_spec(axis)
     lsp = _traced_L_spec(axis)
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, lsp, spec, spec, spec, P()),
         out_specs=(spec, lsp), check_vma=False)(o, L, q, kb, vb, j)
 
@@ -2178,7 +2178,7 @@ def _sharded_attention_jit(q, k, v, *, local_fn, mesh: Mesh, axis: str,
     body = functools.partial(local_fn, axis=axis, causal=causal,
                              **local_kwargs)
     spec = _seq_spec(axis)
-    return mesh_lib.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -24,7 +24,6 @@ exchange round.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -32,17 +31,6 @@ from jax import lax
 def ring_perm(p: int, shift: int = 1) -> list[tuple[int, int]]:
     """Permutation sending each ring member's value to ``(i + shift) % p``."""
     return [(i, (i + shift) % p) for i in range(p)]
-
-
-def axis_size(axis_name: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    # jax <= 0.4.37 has no lax.axis_size; core.axis_frame(name) IS the
-    # static size there (trace_ctx.axis_env.axis_size).
-    return jax.core.axis_frame(axis_name)
-
-
-_axis_size = axis_size
 
 
 def _chaos_ghost(ghost: jnp.ndarray) -> jnp.ndarray:
@@ -91,7 +79,7 @@ def halo_pad_y(block: jnp.ndarray, axis_name: str = "y", depth: int = 1) -> jnp.
     whatever the slice holds).
     """
     _note_exchange("y", axis_name)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     # My top ghost rows are the *last* rows of my predecessor: everyone
     # sends their bottom edge forward around the ring.
     top = _chaos_ghost(
@@ -109,7 +97,7 @@ def halo_pad_x(block: jnp.ndarray, axis_name: str = "x", depth: int = 1) -> jnp.
     :func:`halo_pad_y` for the radius/dtype-generic contract).
     """
     _note_exchange("x", axis_name)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     left = _chaos_ghost(
         lax.ppermute(block[..., -depth:], axis_name, ring_perm(p, 1)))
     right = lax.ppermute(block[..., :depth], axis_name, ring_perm(p, -1))
@@ -134,7 +122,7 @@ def packed_halo_y(
     if pad == 0:
         return halo_pad_y(e, axis_name, h)
     _note_exchange("packed_y", axis_name)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s = h + 1 + pad // 32
     # Chaos wraps the INCOMING top ghost only (injection-point parity
     # with halo_pad_y): `dn` also refreshes the wrap shard's mirror
@@ -172,7 +160,7 @@ def packed_halo_x(
     if pad == 0:
         return halo_pad_x(block, axis_name, hx)
     _note_exchange("packed_x", axis_name)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s = hx + pad
     # Chaos on the incoming left ghost only — `right` also feeds the
     # wrap shard's mirror-column refresh (see packed_halo_y).

@@ -97,10 +97,10 @@ def overlap_enabled() -> bool:
 def rdma_requested() -> bool:
     """Whether ``MOMP_HALO_RDMA=1`` asks for the explicit Pallas
     async-remote-copy ghost path (default OFF: the deferred ``ppermute``
-    schedule already overlaps via XLA's latency-hiding scheduler, and
-    the RDMA kernels are the chip rung the r08 queue exercises —
-    ``launchers/queue_r08/30_partitioned_halo_ring.sh``; see DESIGN.md
-    §20 for the layout matrix)."""
+    schedule already overlaps via XLA's latency-hiding scheduler; the
+    RDMA kernels compile for a described v5e (tests/test_chip_compile.py)
+    but have not run on a chip; see DESIGN.md §20 for the layout
+    matrix)."""
     return os.environ.get(ENV_RDMA, "0") == "1"
 
 
@@ -221,7 +221,7 @@ def ghosts_y(block: jnp.ndarray, depth: int,
     the interior compute can proceed while they fly. Chaos hook on the
     top ghost, mirroring the sequential path's injection point."""
     halo._note_exchange("y-overlap", axis_name)
-    p = halo._axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     top = halo._chaos_ghost(lax.ppermute(
         block[..., -depth:, :], axis_name, halo.ring_perm(p, 1)))
     bot = lax.ppermute(
@@ -234,7 +234,7 @@ def ghosts_x(block: jnp.ndarray, depth: int,
     """The x ghost pair ``(left, right)`` — :func:`ghosts_y` transposed
     to the last axis (cf. ``halo.halo_pad_x``)."""
     halo._note_exchange("x-overlap", axis_name)
-    p = halo._axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     left = halo._chaos_ghost(lax.ppermute(
         block[..., -depth:], axis_name, halo.ring_perm(p, 1)))
     right = lax.ppermute(
@@ -250,7 +250,7 @@ def packed_ghosts_y(q: jnp.ndarray, h: int,
     the sequential funnel-shift path). One halo word carries 32 boards'
     worth of ghost rows — the overlap win multiplied."""
     halo._note_exchange("packed_y-overlap", axis_name)
-    p = halo._axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     top = halo._chaos_ghost(
         lax.ppermute(q[-h:], axis_name, halo.ring_perm(p, 1)))
     bot = lax.ppermute(q[:h], axis_name, halo.ring_perm(p, -1))
@@ -273,9 +273,8 @@ def _rdma_edge_pair(fwd_edge: jnp.ndarray, bwd_edge: jnp.ndarray,
     successor's ``bwd_edge``. Semantically identical to a ``ppermute``
     pair; the difference is WHO schedules the transfer: here the DMA
     engines are driven directly instead of through the
-    collective-permute lowering. Real-TPU only (``MOMP_HALO_RDMA=1``) —
-    the r08 launcher exercises it on chip; CPU CI stays on the deferred
-    ``ppermute`` schedule. Transport only: chaos injection and ghost
+    collective-permute lowering. Real-TPU only (``MOMP_HALO_RDMA=1``);
+    CPU CI stays on the deferred ``ppermute`` schedule. Transport only: chaos injection and ghost
     orientation live in the ``_rdma_ghosts_*`` wrappers so every layout
     funnels through ``halo._chaos_ghost`` exactly like the deferred
     path.
@@ -283,24 +282,27 @@ def _rdma_edge_pair(fwd_edge: jnp.ndarray, bwd_edge: jnp.ndarray,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # Peers are named by their index on ``axis_name`` (MESH ids); the
+    # other mesh axes keep this device's own coordinate, so on a 2-D mesh
+    # the transfer stays on the ring it is meant for.
+    mesh_id = pltpu.DeviceIdType.MESH
+
     def kernel(fwd, bwd, prev_out, next_out, s1, r1, s2, r2):
         i = lax.axis_index(axis_name)
-        nxt = lax.rem(i + 1, p)
-        prv = lax.rem(i + p - 1, p)
+        nxt = {axis_name: lax.rem(i + 1, p)}
+        prv = {axis_name: lax.rem(i + p - 1, p)}
         barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, 1, device_id=(nxt,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_signal(
-            barrier, 1, device_id=(prv,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+        pltpu.semaphore_signal(barrier, 1, device_id=nxt,
+                               device_id_type=mesh_id)
+        pltpu.semaphore_signal(barrier, 1, device_id=prv,
+                               device_id_type=mesh_id)
         pltpu.semaphore_wait(barrier, 2)
         send_fwd = pltpu.make_async_remote_copy(
             src_ref=fwd, dst_ref=prev_out, send_sem=s1, recv_sem=r1,
-            device_id=(nxt,), device_id_type=pltpu.DeviceIdType.LOGICAL)
+            device_id=nxt, device_id_type=mesh_id)
         send_bwd = pltpu.make_async_remote_copy(
             src_ref=bwd, dst_ref=next_out, send_sem=s2, recv_sem=r2,
-            device_id=(prv,), device_id_type=pltpu.DeviceIdType.LOGICAL)
+            device_id=prv, device_id_type=mesh_id)
         send_fwd.start()
         send_bwd.start()
         send_fwd.wait()
@@ -310,10 +312,10 @@ def _rdma_edge_pair(fwd_edge: jnp.ndarray, bwd_edge: jnp.ndarray,
         kernel,
         out_shape=(jax.ShapeDtypeStruct(fwd_edge.shape, fwd_edge.dtype),
                    jax.ShapeDtypeStruct(bwd_edge.shape, bwd_edge.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 4,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(fwd_edge, bwd_edge)
     return from_prev, from_next
@@ -483,7 +485,7 @@ def _partitioned_fused_step(plan: HaloPlan, step_fn, block: jnp.ndarray
             [block[..., -d:, :], block, block[..., :d, :]], axis=-2)
         interior = _steps(step_fn, base, k)
         lead, tail = base[..., : 2 * d], base[..., -2 * d:]
-        p = halo._axis_size("x")
+        p = lax.axis_size("x")
         for _ in range(k // b):
             halo._note_exchange("x-part", "x")
             if rdma:
@@ -513,7 +515,7 @@ def _partitioned_fused_step(plan: HaloPlan, step_fn, block: jnp.ndarray
             [block[..., -d:], block, block[..., :d]], axis=-1)
     interior = _steps(step_fn, base, k)
     lead, tail = base[..., : 2 * d, :], base[..., -2 * d:, :]
-    p = halo._axis_size("y")
+    p = lax.axis_size("y")
     for _ in range(k // b):
         halo._note_exchange("y-part", "y")
         if rdma:

@@ -18,47 +18,17 @@ dimension (axis 1).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# Classic GSPMD propagation (Auto) rather than sharding-in-types (Explicit,
-# the jax>=0.9 make_mesh default): the roll-based global step relies on XLA
-# propagating shardings through circular shifts of arbitrary (uneven) sizes.
-# ``AxisType`` only exists from jax 0.4.38ish onward (and ``make_mesh`` only
-# grew the ``axis_types`` kwarg alongside it); on older jax every mesh axis
-# IS implicitly Auto, so the portable form is: pass ``axis_types`` only when
-# the installed jax knows the enum, otherwise rely on the implicit default.
-try:  # pragma: no cover - exercised as one branch per installed jax
-    from jax.sharding import AxisType
-except ImportError:  # jax <= 0.4.37: Auto semantics are the only semantics
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 AXIS_Y = "y"
 AXIS_X = "x"
 
-# ``jax.shard_map`` is also a recent promotion: on jax <= 0.4.37 it lives at
-# ``jax.experimental.shard_map.shard_map`` and spells the replication check
-# ``check_rep`` instead of ``check_vma``. Every shard_map in this codebase
-# goes through this wrapper so call sites stay version-agnostic.
-if hasattr(jax, "shard_map"):  # pragma: no cover - one branch per jax
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        """Version-portable ``jax.shard_map``."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # pragma: no cover - one branch per jax
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        """Version-portable ``jax.shard_map`` (pre-0.4.38 spelling)."""
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=check_vma)
-
-
+# Classic GSPMD propagation (Auto) rather than sharding-in-types (Explicit,
+# the jax>=0.9 make_mesh default): the roll-based global step relies on XLA
+# propagating shardings through circular shifts of arbitrary (uneven) sizes.
 def _auto_mesh(shape: tuple[int, ...], names: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis semantics on every jax version."""
-    if AxisType is None:
-        return jax.make_mesh(shape, names)
+    """``jax.make_mesh`` with Auto semantics on every axis."""
     return jax.make_mesh(shape, names,
                          axis_types=tuple(AxisType.Auto for _ in names))
 

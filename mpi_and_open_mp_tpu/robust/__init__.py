@@ -1,4 +1,4 @@
-"""Fault injection, guards, watchdogged dispatch, preemption-safe resume.
+"""Fault injection, guards, bounded retry waits, preemption-safe resume.
 
 The robustness layer of the stack — four small modules threaded through
 ``parallel/``, ``models/``, ``bench.py`` and the launchers:
@@ -12,9 +12,8 @@ The robustness layer of the stack — four small modules threaded through
     retry with ``:recovered`` provenance — plus the validators and the
     process-wide recovery log recorders publish.
 ``watchdog``
-    Subprocess device probe with bounded exponential backoff and
-    CPU-degrade on exhaustion; probes abandon, never kill (the relay
-    rule).
+    Bounded, seeded exponential backoff for the serving daemon's
+    re-dispatch ladder.
 ``preempt``
     SIGTERM/SIGINT → checkpoint-flush-at-segment-boundary → exit 75,
     and the :class:`Preempted` contract drivers/queues key on.

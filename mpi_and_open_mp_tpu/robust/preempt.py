@@ -4,8 +4,8 @@ The reference's answer to a preempted PBS job was to requeue and restart
 from step 0. Here a SIGTERM/SIGINT lands as a *flag* checked at segment
 boundaries of ``LifeSim.run``: the loop flushes a final checkpoint and
 raises :class:`Preempted`, which drivers translate to exit code 75
-(EX_TEMPFAIL) — the ``tpu_queue_loop.sh`` queue keeps the job and its
-``--resume`` continues the bracket from the flushed step.
+(EX_TEMPFAIL) — a batch scheduler requeues the job and its ``--resume``
+continues the bracket from the flushed step.
 
 Handlers only *set the flag* — no checkpoint IO, no device work, nothing
 async-signal-unsafe runs inside the handler itself. The flush happens in

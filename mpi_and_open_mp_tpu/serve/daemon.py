@@ -24,7 +24,7 @@ take the process down:
   dispatches (``robust.preempt``): the in-flight batch completes, the
   pending queue snapshots through the crash-atomic CRC state checkpoint
   (``utils.checkpoint.save_state``), and :class:`Preempted` propagates so
-  drivers exit 75 (EX_TEMPFAIL) for the ``tpu_queue_loop.sh`` requeue;
+  drivers exit 75 (EX_TEMPFAIL) for the scheduler's requeue;
   ``--resume`` restores every drained ticket, so an admitted request is
   never silently dropped. ``MOMP_CHAOS preempt=<k>`` rehearses the same
   path after ``k`` dispatched batches, and ``serve_fail=<k>`` drives the

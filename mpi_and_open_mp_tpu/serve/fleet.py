@@ -12,7 +12,9 @@ FleetRouter` contract:
 * The module CLI (``python -m mpi_and_open_mp_tpu.serve.fleet``) — the
   cross-process deployment CI's ``fleet-chaos-smoke`` kills for real: a
   parent partitions a seeded burst by consistent hash, writes one spool
-  per worker, spawns one subprocess per worker (``--worker-main``),
+  per worker, spawns one subprocess per worker (``--worker-main``; more
+  than one only under ``JAX_PLATFORMS=cpu``, since a process on the
+  accelerator holds every chip of its host),
   and when a worker dies (rc 137 from the ``kill_worker=<i>:<k>`` chaos
   token — indistinguishable from ``kill -9``) replays the victim's WAL,
   journals the ``re-homed`` sheds back to it, and spawns recovery
@@ -51,6 +53,7 @@ from mpi_and_open_mp_tpu.serve.queue import DONE, SHED, Ticket
 from mpi_and_open_mp_tpu.serve.router import (
     DEFAULT_MISS_K, DEFAULT_VNODES, FleetRollup, FleetRouter)
 from mpi_and_open_mp_tpu.utils import checkpoint as checkpoint_mod
+from mpi_and_open_mp_tpu.utils import runtime
 
 SPOOL_SCHEMA = "momp-fleet-spool/1"
 
@@ -520,6 +523,11 @@ def _worker_main(args) -> int:
     """One fleet worker: drain a spool under the full daemon contract
     (WAL, chaos sites, supervision ladder), print one JSON line."""
     idx = args.worker_main
+    try:
+        runtime.require_backend()
+    except RuntimeError as e:
+        print(json.dumps({"worker": idx, "error": str(e)}))
+        return 1
     spool = checkpoint_mod.restore_state(args.spool)
     if spool.get("schema") != SPOOL_SCHEMA:
         print(json.dumps({"worker": idx, "error": "bad spool schema"}))
@@ -642,6 +650,14 @@ def main(argv=None) -> int:
         if not (args.spool and args.wal):
             build_parser().error("--worker-main requires --spool and --wal")
         return _worker_main(args)
+    if args.workers > 1 and not runtime.cpu_pinned():
+        # A TPU process claims every chip of its host, so a second worker
+        # would fail or hang on the first one's chip.
+        build_parser().error(
+            f"--workers {args.workers}: on an accelerator each worker "
+            "process holds every chip of the host, so the fleet runs one "
+            "worker; set JAX_PLATFORMS=cpu for a multi-worker CPU fleet, "
+            "or use the in-process Fleet (bench.py --serve N --fleet W)")
 
     from mpi_and_open_mp_tpu.serve.router import (
         ConsistentHashRing, affinity_key)

@@ -8,8 +8,8 @@ numbers they are judged against.
 The two admission budgets guard the two resources a shape-bucketed
 server can actually exhaust:
 
-* **Depth** — pending tickets queue host memory and, at ~70 ms RTT per
-  dispatch through the relay, wall time: a queue deeper than the worker
+* **Depth** — pending tickets queue host memory and, at one host
+  round trip per dispatch, wall time: a queue deeper than the worker
   can drain inside the per-request timeout is already lost, so it is
   cheaper (and honest) to reject at the door with an explicit reason
   than to time the request out later.

@@ -317,7 +317,7 @@ def make_sharded_runner(spec: StencilSpec, mesh, layout: str,
     import jax.numpy as jnp
     from jax import lax
 
-    from mpi_and_open_mp_tpu.parallel import haloplan, mesh as mesh_lib
+    from mpi_and_open_mp_tpu.parallel import haloplan
 
     if family == "sep":
         _require_sep(spec)
@@ -356,7 +356,7 @@ def make_sharded_runner(spec: StencilSpec, mesh, layout: str,
 
     def make_smapped(k: int):
         pk = plan_for(k)
-        return mesh_lib.shard_map(
+        return jax.shard_map(
             lambda b: haloplan.fused_step(pk, step_fn, b),
             mesh=mesh, in_specs=pspec, out_specs=pspec, check_vma=False)
 

@@ -148,7 +148,6 @@ def _compiled_round(spec: StencilSpec, mesh, layout: str, tile: int,
     from jax.sharding import PartitionSpec as P
 
     from mpi_and_open_mp_tpu.parallel import haloplan
-    from mpi_and_open_mp_tpu.parallel import mesh as mesh_lib
 
     t, r = tile, spec.radius
     d_halo = r * fuse               # gathered halo / ghost depth
@@ -227,7 +226,7 @@ def _compiled_round(spec: StencilSpec, mesh, layout: str, tile: int,
             live |= (newblk[:, -b:] != 0).any()
         return newblk, flags[None], live.reshape(1)
 
-    smapped = mesh_lib.shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, coords_spec, nvalid_spec),
         out_specs=(pspec, flags_spec, nvalid_spec),

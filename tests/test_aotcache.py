@@ -68,6 +68,11 @@ def test_cold_build_then_warm_hit_zero_retraces(tmp_path, make_board):
     counter once per bucket) and persists; pass 2 — a fresh AOTCache,
     i.e. a restarted process's view — deserializes every program and
     runs it with ZERO life_batch retrace ticks, bit-exact."""
+    import jax
+
+    # An earlier test in this process may already hold these programs in
+    # the in-memory jit cache, which would hide the cold build's traces.
+    jax.clear_caches()
     metrics.reset()
     c1 = aotcache.AOTCache(tmp_path)
     w1 = c1.warm([((16, 16), "uint8")], 4)

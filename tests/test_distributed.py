@@ -16,10 +16,6 @@ import socket
 import subprocess
 import sys
 
-import pytest
-
-import conftest
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "_dist_worker.py")
 
@@ -28,11 +24,6 @@ def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("localhost", 0))
         return s.getsockname()[1]
-
-
-pytestmark = pytest.mark.skipif(
-    not conftest.multiprocess_cpu_supported(),
-    reason="installed jaxlib's CPU backend cannot compile multi-process SPMD")
 
 
 def test_two_process_distributed_run():

@@ -728,3 +728,21 @@ def test_autoscale_adds_on_breach_drains_on_surplus(make_board):
                                     policy_mod.SCALE_DRAIN]
     assert len(f.router.live_workers()) == 2  # back at min capacity
     assert f.summary()["balanced"]
+
+
+def test_fleet_cli_refuses_workers_sharing_a_chip(monkeypatch, capsys):
+    """Off the explicit CPU pin the fleet CLI runs at most one worker
+    process (each would claim every chip of the host), and a worker
+    refuses a CPU backend nobody asked for."""
+    from mpi_and_open_mp_tpu.serve import fleet as fleet_mod
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit) as ei:
+        fleet_mod.main(["--workers", "2"])
+    assert ei.value.code == 2
+    assert "JAX_PLATFORMS=cpu" in capsys.readouterr().err
+    rc = fleet_mod.main(["--worker-main", "0", "--spool", "unused",
+                         "--wal", "unused"])
+    assert rc == 1
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["worker"] == 0 and "not a TPU" in line["error"]

@@ -207,7 +207,7 @@ def test_direct_fused_step_schedules_bit_equal(make_board):
                          NamedSharding(mesh, pspec))
 
     def smapped(fn):
-        return jax.jit(mesh_lib.shard_map(
+        return jax.jit(jax.shard_map(
             lambda b: fn(plan, step_fn, b), mesh=mesh,
             in_specs=pspec, out_specs=pspec, check_vma=False))
 

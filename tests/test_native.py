@@ -19,6 +19,9 @@ def built_lib():
     rc = subprocess.run(
         ["make", "-C", os.path.join(REPO, "native")], capture_output=True
     )
+    # An earlier test in this process may have tried (and failed) to load
+    # the library before it was built; forget that attempt.
+    native._LIB, native._TRIED = None, False
     if rc.returncode != 0 or not native.available():
         pytest.skip("native toolchain unavailable")
 

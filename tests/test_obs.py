@@ -575,9 +575,16 @@ def test_roofline_placement_and_bound():
 def test_peaks_env_override(monkeypatch):
     monkeypatch.setenv("MOMP_PEAK_FLOPS", "5e12")
     monkeypatch.setenv("MOMP_PEAK_BYTES_S", "1e11")
-    flops, bw, label = profile.peaks_for("weird-part")
+    flops, bw, label = profile.peaks_for("cpu")
     assert (flops, bw) == (5e12, 1e11)
-    assert label == "cpu-nominal"  # unknown kind → nominal default label
+    assert label == "cpu-nominal"
+
+
+def test_peaks_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="weird-part"):
+        profile.peaks_for("weird-part")
+    with pytest.raises(ValueError):
+        profile.peaks_for(None)
 
 
 def test_record_memory_gauges_live_and_watermark():

@@ -39,7 +39,7 @@ def test_packed_halo_y_periodic_extension():
         bitlife.pack_board_exact(jnp.asarray(frame)),
         NamedSharding(mesh, P("y", None)),
     )
-    ext = jax.jit(mesh_lib.shard_map(
+    ext = jax.jit(jax.shard_map(
         lambda q: halo.packed_halo_y(q, "y", plan.h, pad=plan.pad_y),
         mesh=mesh, in_specs=P("y", None), out_specs=P("y", None),
         check_vma=False,
@@ -71,7 +71,7 @@ def test_packed_halo_x_periodic_extension():
         bitlife.pack_board_exact(jnp.asarray(frame)),
         NamedSharding(mesh, P(None, "x")),
     )
-    ext = jax.jit(mesh_lib.shard_map(
+    ext = jax.jit(jax.shard_map(
         lambda q: halo.packed_halo_x(q, "x", plan.hx, pad=plan.pad_x),
         mesh=mesh, in_specs=P(None, "x"), out_specs=P(None, "x"),
         check_vma=False,
@@ -106,7 +106,7 @@ def test_packed_halo_degenerates_to_plain_pad_when_aligned():
         b = halo.halo_pad_y(q, "y", 2)
         return a, b
 
-    a, b = jax.jit(mesh_lib.shard_map(
+    a, b = jax.jit(jax.shard_map(
         both, mesh=mesh, in_specs=P("y", None),
         out_specs=(P("y", None), P("y", None)), check_vma=False,
     ))(packed)
@@ -123,7 +123,7 @@ def test_packed_halo_degenerates_to_plain_pad_when_aligned():
         b = halo.halo_pad_x(q, "x", 16)
         return a, b
 
-    a, b = jax.jit(mesh_lib.shard_map(
+    a, b = jax.jit(jax.shard_map(
         both_x, mesh=mesh_x, in_specs=P(None, "x"),
         out_specs=(P(None, "x"), P(None, "x")), check_vma=False,
     ))(packed_x)

@@ -9,7 +9,7 @@ sequential oracle. CPU CI executes the RDMA *schedule* through a
 ``ppermute`` stand-in with identical semantics (predecessor's forward
 edge, successor's backward edge), so the exchange order, corner
 assembly, and chaos hooks are exercised here and only the DMA transport
-itself is chip-gated (``launchers/queue_r08``). Chaos must reach every
+itself is chip-only. Chaos must reach every
 new exchange (a corrupted ghost diverges the run; the LifeSim guard
 ladder recovers with ``:recovered`` provenance), and the tuner's
 independent interior x boundary depth axis must keep the coupled-depth

@@ -445,8 +445,6 @@ def test_bench_serve_phase_fields(monkeypatch, capsys):
         sys.path.insert(0, REPO)
     import bench
 
-    monkeypatch.setattr(bench, "_probe_devices",
-                        lambda timeout_s: (False, "stubbed"))
     rc = bench.main(["--board", "32", "--steps", "16", "--serve", "6"])
     assert rc == 0
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])

@@ -234,7 +234,7 @@ R2 = stencils.StencilSpec(
 def _sharded_step(spec, board, mesh, in_spec):
     """One torus step via halo_pad_2d + step_padded under shard_map."""
     arr = jax.device_put(jnp.asarray(board), NamedSharding(mesh, in_spec))
-    fn = jax.jit(mesh_lib.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda blk: engine.step_padded(
             spec, halo.halo_pad_2d(blk, depth=spec.radius)),
         mesh=mesh, in_specs=in_spec, out_specs=in_spec, check_vma=False,
@@ -249,7 +249,7 @@ def test_halo_pad_depth2_float_periodic_extension(rng):
     mesh = mesh_lib.make_mesh_1d(4, axis="y")
     arr = jax.device_put(
         jnp.asarray(board), NamedSharding(mesh, P("y", None)))
-    ext = jax.jit(mesh_lib.shard_map(
+    ext = jax.jit(jax.shard_map(
         lambda blk: halo.halo_pad_y(blk, "y", 2),
         mesh=mesh, in_specs=P("y", None), out_specs=P("y", None),
         check_vma=False,
