@@ -2672,33 +2672,12 @@ def _bench(args, state) -> int:
                     3.5 * flops / grad_sec / 1e12, 1),
                 "attention_grad_is_differenced": grad_diff,
             })
-    # Profile phase: compiled-artifact introspection (obs.profile). The
-    # roofline annotation divides the ROLL step's XLA cost model (one
-    # dense stencil step at the bench shape — flops + bytes accessed from
-    # compiled.cost_analysis(), compiled once, nothing executed) by the
-    # measured steady seconds-per-step, so every cups number says how far
-    # it sits from the device's compute/bandwidth ceilings. The model fn
-    # is stamped on the line: on the packed/Pallas paths this is the
-    # algorithmic work of the dense formulation, not the kernel's
-    # internal op count. Failures cost the field, never the line.
+    # Profile phase: live-buffer and device memory gauges (obs.profile)
+    # ride the line's metrics sub-object.
     state["phase"] = "profile"
     from mpi_and_open_mp_tpu.obs import profile as obs_profile
 
-    prof_fields = {}
-    try:
-        from mpi_and_open_mp_tpu.ops.life_ops import life_step_roll
-
-        step_cost = obs_profile.cost(
-            life_step_roll, jax.ShapeDtypeStruct((NY, NX), np.uint8),
-            name="life_step_roll")
-        rf = obs_profile.roofline(step_cost["flops"], step_cost["bytes"],
-                                  steady / STEPS, device_kind=device_kind)
-        rf["model"] = "life_step_roll"
-        rf["compile_seconds"] = step_cost["compile_seconds"]
-        prof_fields["roofline"] = rf
-        obs_profile.record_memory_gauges()
-    except Exception as e:  # noqa: BLE001
-        prof_fields["roofline_error"] = f"{type(e).__name__}: {e}"[:200]
+    obs_profile.record_memory_gauges()
     if "attention_32k_causal_tflops" in sharded:
         # The attention twin rides only when the fwd timing landed: its
         # FLOPs are exact (2hn²d causal), so the roofline is just the
@@ -2801,7 +2780,6 @@ def _bench(args, state) -> int:
         **sparse_sharded,
         **radius_ab,
         **sharded,
-        **prof_fields,
         **trace_fields,
         **metrics_fields,
     }

@@ -88,7 +88,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "restarts both warm and tuned; the resume status "
                         "line (stderr JSON) carries plan_source")
     p.add_argument("--profile", metavar="DIR", default=None,
-                   help="capture a jax.profiler trace of the run into DIR")
+                   help="capture a jax.profiler trace of the run into DIR; "
+                        "unless --trace or MOMP_TRACE names a sink, the "
+                        "obs spans go to DIR/spans.jsonl, so the profile "
+                        "shows the life.* spans over the device ops")
     p.add_argument("--trace", metavar="PATH", default=None,
                    help="write obs span/event JSONL here (sets MOMP_TRACE; "
                         "read it back with analysis/trace_report.py)")
@@ -235,6 +238,10 @@ def main(argv=None) -> int:
         # Before any sim work so every span of this run lands in the sink
         # (the sink is cached per env value; appends across invocations).
         os.environ["MOMP_TRACE"] = args.trace
+    elif args.profile and not os.environ.get("MOMP_TRACE"):
+        # A live span is also a profiler annotation: the profile then
+        # names the host phase behind each device idle gap.
+        os.environ["MOMP_TRACE"] = os.path.join(args.profile, "spans.jsonl")
     from mpi_and_open_mp_tpu.obs import trace
 
     cfg = load_config(args.cfg)

@@ -87,6 +87,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from mpi_and_open_mp_tpu.obs import trace
 from mpi_and_open_mp_tpu.ops import life_ops
 from mpi_and_open_mp_tpu.parallel import halo, haloplan, mesh as mesh_lib
 from mpi_and_open_mp_tpu.utils import vtk as vtk_lib
@@ -386,10 +387,10 @@ class LifeSim:
             board = full
         self._initial = board
         self._initial_step = int(initial_step)
-        board = jnp.asarray(board, dtype=dtype)
-        self.board = (
-            jax.device_put(board, self.sharding) if self.sharding else board
-        )
+        # The number of the current run: reset() starts the next one, and
+        # every span of a run carries it as ``run``.
+        self._run_id = 0
+        self.reset()
         self._advance = self._build_advance()
 
     # ---------------------------------------------------------- step builders
@@ -466,7 +467,8 @@ class LifeSim:
                         b = lax.with_sharding_constraint(b, sharding)
                     return b
 
-                return lax.fori_loop(0, n, body, board)
+                with jax.named_scope("life_advance"):
+                    return lax.fori_loop(0, n, body, board)
 
             return advance
 
@@ -496,11 +498,13 @@ class LifeSim:
         def advance(board, n):
             _note_retrace("life_advance_halo")
             rounds, rem = divmod(n, k)
-            board = lax.fori_loop(0, rounds, lambda _, b: smapped_k(b), board)
-            if rem:
-                if rem not in smapped_cache:
-                    smapped_cache[rem] = make_smapped(rem)
-                board = smapped_cache[rem](board)
+            with jax.named_scope("life_advance"):
+                board = lax.fori_loop(
+                    0, rounds, lambda _, b: smapped_k(b), board)
+                if rem:
+                    if rem not in smapped_cache:
+                        smapped_cache[rem] = make_smapped(rem)
+                    board = smapped_cache[rem](board)
             return board
 
         return advance
@@ -531,7 +535,8 @@ class LifeSim:
         def advance(board, n):
             _note_retrace("life_advance_roll_batch")
             step = jax.vmap(life_ops.life_step_roll)
-            return lax.fori_loop(0, n, lambda _, b: step(b), board)
+            with jax.named_scope("life_advance"):
+                return lax.fori_loop(0, n, lambda _, b: step(b), board)
 
         return advance
 
@@ -587,8 +592,9 @@ class LifeSim:
             @jax.jit
             def advance(board, n):
                 _note_retrace("life_advance_bitfused")
-                out = life_run_vmem(board[:ny, :nx], jnp.int32(n))
-                out = jnp.pad(out, ((0, fy - ny), (0, fx - nx)))
+                with jax.named_scope("life_advance"):
+                    out = life_run_vmem(board[:ny, :nx], jnp.int32(n))
+                    out = jnp.pad(out, ((0, fy - ny), (0, fx - nx)))
                 return lax.with_sharding_constraint(
                     out.astype(dtype), self.sharding)
 
@@ -671,7 +677,8 @@ class LifeSim:
         @jax.jit
         def advance(board, n):
             _note_retrace("life_advance_bitfused")
-            return smapped(board, jnp.int32(n))
+            with jax.named_scope("life_advance"):
+                return smapped(board, jnp.int32(n))
 
         return advance
 
@@ -698,11 +705,20 @@ class LifeSim:
         anchor_sync(self.board)
 
     def reset(self) -> None:
-        """Restore the initial board without rebuilding compiled steppers."""
-        board = jnp.asarray(self._initial, dtype=self.dtype)
-        self.board = (
-            jax.device_put(board, self.sharding) if self.sharding else board
-        )
+        """Restore the initial board without rebuilding compiled steppers.
+
+        This starts the next run. Under ``MOMP_TRACE`` the upload is the
+        span ``life.upload``, anchored on the new board, so that it covers
+        the host-to-device transfer and not only its enqueue.
+        """
+        self._run_id += 1
+        with trace.span("life.upload", run=self._run_id) as sp:
+            board = jnp.asarray(self._initial, dtype=self.dtype)
+            self.board = (
+                jax.device_put(board, self.sharding) if self.sharding
+                else board
+            )
+            sp.set(bytes=self.board.nbytes).anchor(self.board)
         self.step_count = self._initial_step
 
     def save_checkpoint(self, path: str | os.PathLike) -> None:
@@ -962,21 +978,26 @@ class LifeSim:
         addressable from one process, so the gather goes through a
         cross-process allgather — every host gets the full board, the
         multi-host generalisation of the reference's gather-to-root
-        (``5-gather/life_mpi.c:178``).
+        (``5-gather/life_mpi.c:178``). The fetch is synchronous, so the
+        span ``life.collect`` covers the transfer, the host's reordering
+        of the device layout and the crop.
         """
-        if self.board.is_fully_addressable:
-            full = np.asarray(
-                jax.device_get(self.board), dtype=self._np_dtype)
-        else:
-            from jax.experimental import multihost_utils
+        with trace.span("life.collect", run=self._run_id,
+                        bytes=self.board.nbytes):
+            if self.board.is_fully_addressable:
+                full = np.asarray(
+                    jax.device_get(self.board), dtype=self._np_dtype)
+            else:
+                from jax.experimental import multihost_utils
 
-            full = np.asarray(
-                multihost_utils.process_allgather(self.board, tiled=True),
-                dtype=self._np_dtype,
-            )
-        # Ellipsis crop: batched boards are (B, ny, nx), the crop applies
-        # to the trailing board axes either way.
-        return full[..., : self.cfg.ny, : self.cfg.nx]
+                full = np.asarray(
+                    multihost_utils.process_allgather(
+                        self.board, tiled=True),
+                    dtype=self._np_dtype,
+                )
+            # Ellipsis crop: batched boards are (B, ny, nx), the crop
+            # applies to the trailing board axes either way.
+            return full[..., : self.cfg.ny, : self.cfg.nx]
 
     def save_snapshot(self) -> str:
         assert self.outdir is not None, "LifeSim(outdir=...) required to save"
@@ -985,10 +1006,14 @@ class LifeSim:
         # allgather) — every process must enter it; only process 0 writes
         # the file, the reference's write-from-one-rank discipline
         # (3-life/life_mpi.c:54-57; shared-FS double-writes otherwise).
-        board = self.collect()
-        if jax.process_index() == 0:
-            os.makedirs(self.outdir, exist_ok=True)
-            vtk_lib.write_vtk(path, board)
+        with trace.span("life.snapshot", run=self._run_id,
+                        step=self.step_count):
+            board = self.collect()
+            if jax.process_index() == 0:
+                with trace.span("life.vtk_write", run=self._run_id) as sp:
+                    os.makedirs(self.outdir, exist_ok=True)
+                    vtk_lib.write_vtk(path, board)
+                    sp.set(bytes=os.path.getsize(path))
         return path
 
     def save_state(self) -> None:
@@ -1016,7 +1041,6 @@ class LifeSim:
         step) or fire a simulated preemption at a fixed step; guards are
         armed by the plan or ``MOMP_GUARD=1``.
         """
-        from mpi_and_open_mp_tpu.obs import trace
         from mpi_and_open_mp_tpu.robust import chaos, guards, preempt
 
         cfg = self.cfg
@@ -1038,6 +1062,7 @@ class LifeSim:
             if cfg.steps > self.step_count:
                 with trace.span(
                     "life.advance",
+                    run=self._run_id,
                     steps=cfg.steps - self.step_count,
                     impl=self.impl,
                     layout=self.layout,
@@ -1067,6 +1092,7 @@ class LifeSim:
                 next_stop = self._next_stop(i, save)
                 with trace.span(
                     "life.segment",
+                    run=self._run_id,
                     start=i,
                     stop=next_stop,
                     impl=self.impl,

@@ -9,11 +9,13 @@ when off:
 ``trace``
     Nestable spans with a context-manager API, monotonic durations
     (``utils.timing.Timer`` is the clock), process/host ids, and a JSONL
-    sink selected by ``MOMP_TRACE=path``. Spans close through
-    ``anchor_sync`` so async device work is attributed to the span that
-    dispatched it. When ``MOMP_TRACE`` is unset every call degenerates to
-    one env lookup returning a shared no-op span — the chaos layer's
-    ``is None`` discipline.
+    sink selected by ``MOMP_TRACE=path``. An anchored span closes through
+    ``jax.block_until_ready`` so async device work is attributed to the
+    span that dispatched it, and a live span is a
+    ``jax.profiler.TraceAnnotation`` of its name, so it lies on the
+    profiler's clock beside the device ops. When ``MOMP_TRACE`` is unset
+    every call degenerates to one env lookup returning a shared no-op
+    span — the chaos layer's ``is None`` discipline.
 ``metrics``
     Process-wide counters/gauges/histograms: jit retraces per function,
     ring hops per engine, traced halo exchanges, guard validations and
@@ -41,11 +43,8 @@ when off:
     baseline store ``analysis/regression_sentinel.py`` judges new runs
     against. Stdlib-only; safe on chip-forbidden hosts.
 ``profile``
-    Compiled-artifact introspection: ``cost_analysis()`` FLOPs/bytes per
-    phase, roofline placement against per-device-kind peaks, compile-time
-    histograms and live-buffer/memory gauges through the metrics
-    registry, and cost-cache hit/miss counters extending the retrace
-    accounting.
+    Per-device-kind peak tables and live-buffer/memory gauges through
+    the metrics registry.
 """
 
 from mpi_and_open_mp_tpu.obs import (  # noqa: F401

@@ -19,11 +19,18 @@ Spans are written at EXIT (children before parents — reconstruct nesting
 via ``parent``). The duration clock is ``utils.timing.Timer`` — the one
 wall-clock implementation in the framework.
 
+A live span also enters ``jax.profiler.TraceAnnotation(name)`` for its
+lifetime, so under ``jax.profiler.trace`` it shows on the host's line of
+the profile, on the clock the device ops share: an idle gap of the
+device can then be named by the span the host was in. The annotation
+carries the name only; attributes stay in the JSONL record. ``jax`` is
+imported on this live path alone, so ``obs`` imports without it.
+
 Device-work attribution: JAX dispatch is async, so a span that merely
 brackets a dispatch times the enqueue, not the work. Call
 ``span.anchor(tree)`` with the dispatched output; the span then closes
-through ``anchor_sync(tree, fetch_all=True)`` (block + one-element shard
-fetch) so ``dur`` covers the device work the span claims to measure.
+through ``jax.block_until_ready(tree)``, so ``dur`` covers the device
+work the span claims to measure, with no host fetch of its own.
 """
 
 from __future__ import annotations
@@ -34,7 +41,7 @@ import os
 import socket
 import threading
 
-from mpi_and_open_mp_tpu.utils.timing import Timer, anchor_sync
+from mpi_and_open_mp_tpu.utils.timing import Timer
 
 _ENV = "MOMP_TRACE"
 _ENV_HOPS = "MOMP_TRACE_HOPS"
@@ -142,7 +149,8 @@ NULL = _NullSpan()
 class Span:
     """One live span. Use via ``with trace.span(name, **attrs) as sp``."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "_timer", "_ts", "_tree")
+    __slots__ = ("name", "attrs", "id", "parent", "_timer", "_ts", "_tree",
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -152,10 +160,14 @@ class Span:
     def __enter__(self) -> "Span":
         import time
 
+        import jax
+
         stack = _stack()
         self.parent = stack[-1].id if stack else None
         self.id = next(_IDS)
         stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._ts = time.time()
         self._timer = Timer().__enter__()
         return self
@@ -166,8 +178,9 @@ class Span:
         return self
 
     def anchor(self, tree) -> "Span":
-        """Close through ``anchor_sync(tree)``: the span's duration then
-        includes the device work behind these (possibly async) arrays."""
+        """Close through ``jax.block_until_ready(tree)``: the span's
+        duration then includes the device work behind these (possibly
+        async) arrays."""
         self._tree = tree
         return self
 
@@ -177,10 +190,15 @@ class Span:
         return self._timer.elapsed
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._tree is not None and exc_type is None:
-            anchor_sync(self._tree, fetch_all=True)
+        try:
+            if self._tree is not None and exc_type is None:
+                import jax
+
+                jax.block_until_ready(self._tree)
             self._tree = None
-        self._timer.__exit__()
+            self._timer.__exit__()
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()

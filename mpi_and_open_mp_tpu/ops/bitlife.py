@@ -250,6 +250,7 @@ def _run_vmem_bits_jit(packed, steps, *, ny: int, nx: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="life_vmem_bits",
     )(steps, packed)
 
 
@@ -485,6 +486,7 @@ def make_fused_stepper(
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,
+        name="life_fused_tiles",
     )
 
 
@@ -610,6 +612,7 @@ def make_window_stepper(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="life_fused_window",
     )
 
 
@@ -1107,6 +1110,7 @@ def _run_vmem_bits_batch_jit(
             ],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="life_vmem_bits_batch",
         )(steps, packed)
     # Grid over the batch axis: one board resident per program, the
     # stack streamed through VMEM by the pipeline (per-board gate only).
@@ -1120,6 +1124,7 @@ def _run_vmem_bits_batch_jit(
         ],
         out_specs=pl.BlockSpec((1, nw, nxp), lambda i: (i, 0, 0)),
         interpret=interpret,
+        name="life_vmem_bits_batch_grid",
     )(steps, packed)
 
 
@@ -1398,6 +1403,7 @@ def _run_bitsliced_pallas_jit(planes, steps, *, nx: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="life_bitsliced",
     )(steps, planes)
 
 

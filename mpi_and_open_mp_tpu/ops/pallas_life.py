@@ -379,6 +379,7 @@ def stencil_step_padded_pallas(spec, padded: jnp.ndarray) -> jnp.ndarray:
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=_interpret(),
+        name="stencil_step_padded",
     )(padded.astype(compute))
     return out.astype(dtype)
 
@@ -405,5 +406,6 @@ def life_step_padded_pallas(padded: jnp.ndarray) -> jnp.ndarray:
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=_interpret(),
+        name="life_step_padded",
     )(p32)
     return out.astype(dtype)

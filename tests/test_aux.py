@@ -78,12 +78,25 @@ def test_debug_check_passes_and_fails(make_board):
         sim.debug_check()
 
 
-def test_profile_flag_writes_trace(tmp_path, capsys):
+def test_profile_flag_writes_trace(tmp_path, capsys, monkeypatch):
+    """--profile captures a profiler trace and, with no other sink named,
+    turns the span tracer on into DIR/spans.jsonl."""
+    import json
+
+    from mpi_and_open_mp_tpu.obs import trace
+
+    monkeypatch.setenv("MOMP_TRACE", "")  # unset, and restored after
     prof = tmp_path / "trace"
-    rc = life_app.main([os.path.join(FIXTURES, "glider_10x10.cfg"),
-                        "--layout", "serial", "--impl", "roll",
-                        "--profile", str(prof)])
+    try:
+        rc = life_app.main([os.path.join(FIXTURES, "glider_10x10.cfg"),
+                            "--layout", "serial", "--impl", "roll",
+                            "--profile", str(prof)])
+    finally:
+        trace.reset()
     assert rc == 0
     # jax.profiler.trace writes plugins/profile/<ts>/*.
     found = list(prof.rglob("*.xplane.pb")) + list(prof.rglob("*.trace.json.gz"))
     assert found, f"no trace artifacts under {prof}"
+    spans = [json.loads(line)["name"]
+             for line in (prof / "spans.jsonl").read_text().splitlines()]
+    assert {"life.advance", "life.collect", "life.run"} <= set(spans)

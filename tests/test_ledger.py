@@ -262,14 +262,12 @@ def test_sentinel_p99_improvement_and_rate_drop(tmp_path, capsys):
 # ------------------------------------------------------- bench integration
 
 
-def test_bench_cpu_line_carries_roofline_and_lands_in_ledger(
-        tmp_path, capsys, monkeypatch):
+def test_bench_cpu_line_lands_in_ledger(tmp_path, capsys, monkeypatch):
     """The bench line under the explicit CPU pin (the suite never touches
-    a real chip) must carry the provenance stamps, finite roofline
-    fields, and land in the --ledger file as one well-keyed entry the
-    sentinel can read."""
-    import math
-
+    a real chip) must carry the provenance stamps and the memory gauges,
+    no ``roofline`` (a cost model of another step than the one timed),
+    and land in the --ledger file as one well-keyed entry the sentinel
+    can read."""
     sys.path.insert(0, REPO)
     import bench
 
@@ -284,18 +282,7 @@ def test_bench_cpu_line_carries_roofline_and_lands_in_ledger(
     assert rec["dtype"] == "uint8"
     assert "fallback_reason" not in rec
 
-    rf = rec["roofline"]
-    for field in ("flops_per_step", "bytes_per_step", "flops_per_sec",
-                  "bytes_per_sec", "flops_pct", "bw_pct", "roofline_pct",
-                  "compile_seconds"):
-        assert isinstance(rf[field], (int, float)) and math.isfinite(
-            rf[field]), (field, rf)
-    assert rf["bound"] in ("compute", "memory")
-    assert rf["model"] == "life_step_roll"
-
-    cache = [k for k in rec["metrics"]["counters"]
-             if k.startswith("profile.cost_cache{")]
-    assert cache, rec["metrics"]["counters"]
+    assert "roofline" not in rec and "roofline_error" not in rec
     gauges = rec["metrics"]["gauges"]
     assert gauges.get("memory.live_buffer_bytes", 0) >= 0
     assert "memory.live_buffer_watermark_bytes" in gauges

@@ -256,3 +256,129 @@ def test_empty_fixture():
     cfg = load_config_py(os.path.join(FIXTURES, "empty_10x10.cfg"))
     sim = LifeSim(cfg, layout="row", impl="roll")
     assert sim.run(save=False).sum() == 0
+
+
+# ------------------------------------------------------------ run spans
+
+
+@pytest.fixture
+def sink(tmp_path, monkeypatch):
+    from mpi_and_open_mp_tpu.obs import trace
+
+    path = tmp_path / "spans.jsonl"
+    monkeypatch.setenv("MOMP_TRACE", str(path))
+    trace.reset()
+    yield path
+    trace.reset()
+
+
+def _spans(path):
+    import json
+
+    return [r for r in map(json.loads, path.read_text().splitlines())
+            if r["kind"] == "span"]
+
+
+def test_run_spans_share_the_run_number(make_board, sink):
+    """Each reset() + run() writes its upload, advance and collect under
+    one ``run`` number of its own, with the board's bytes."""
+    board = make_board(48, 40)
+    cfg = config_from_board(board, steps=6, save_steps=1000)
+    sim = LifeSim(cfg, layout="cart", impl="halo")
+    sink.write_text("")  # drop the constructor's upload
+    for _ in range(2):
+        sim.reset()
+        np.testing.assert_array_equal(sim.run(save=False),
+                                      oracle_n(board, 6))
+    recs = _spans(sink)
+    assert [r["name"] for r in recs] == ["life.upload", "life.advance",
+                                         "life.collect"] * 2
+    runs = [r["attrs"]["run"] for r in recs]
+    assert runs[:3] == [runs[0]] * 3 and runs[3:] == [runs[0] + 1] * 3
+    for r in recs:
+        if r["name"] != "life.advance":
+            assert r["attrs"]["bytes"] == sim.board.nbytes == 48 * 40
+        assert r["dur"] > 0 and r["parent"] is None
+
+
+def test_snapshot_span_holds_collect_and_vtk_write(make_board, sink,
+                                                   tmp_path):
+    board = make_board(16, 16)
+    cfg = config_from_board(board, steps=4, save_steps=2)
+    sim = LifeSim(cfg, layout="serial", impl="roll",
+                  outdir=tmp_path / "vtk")
+    sim.run(save=True)
+    recs = _spans(sink)
+    snaps = [r for r in recs if r["name"] == "life.snapshot"]
+    assert [s["attrs"]["step"] for s in snaps] == [0, 2]
+    for snap in snaps:
+        kids = [r for r in recs if r["parent"] == snap["id"]]
+        assert [k["name"] for k in kids] == ["life.collect",
+                                             "life.vtk_write"]
+        (write,) = kids[1:]
+        assert write["attrs"]["bytes"] == os.path.getsize(
+            tmp_path / "vtk" / f"life_{snap['attrs']['step']:06d}.vtk")
+        assert {k["attrs"]["run"] for k in kids} == {snap["attrs"]["run"]}
+
+
+def test_untraced_run_adds_no_block_fetch_or_counter(make_board, tmp_path,
+                                                     monkeypatch):
+    """With MOMP_TRACE unset, reset(), run() and collect() neither block
+    nor fetch beyond the collect's own fetch, touch no metrics counter
+    and write no sink; the board comes out as the oracle's."""
+    import jax
+
+    from mpi_and_open_mp_tpu.obs import metrics, trace
+
+    monkeypatch.delenv("MOMP_TRACE", raising=False)
+    trace.reset()
+    board = make_board(48, 40)
+    cfg = config_from_board(board, steps=5, save_steps=1000)
+    sim = LifeSim(cfg, layout="row", impl="halo")
+    sim.warmup()
+    fetches = []
+    get = jax.device_get
+
+    def counted(x):
+        fetches.append(x)
+        return get(x)
+
+    def no_block(*_a, **_k):
+        raise AssertionError("an untraced run blocked")
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    monkeypatch.setattr(jax, "block_until_ready", no_block)
+    before = metrics.snapshot()
+    sim.reset()
+    got = sim.run(save=False)
+    again = sim.collect()
+    assert len(fetches) == 2  # run()'s collect and the explicit one
+    assert metrics.snapshot() == before
+    np.testing.assert_array_equal(got, oracle_n(board, 5))
+    np.testing.assert_array_equal(again, got)
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_run_spans_lie_on_the_profiler_clock(make_board, tmp_path, sink):
+    """A traced run inside jax.profiler.trace puts its upload, advance
+    and collect on the host's line of the profile, in that order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    board = make_board(32, 32)
+    cfg = config_from_board(board, steps=3, save_steps=1000)
+    sim = LifeSim(cfg, layout="serial", impl="roll")
+    sim.warmup()
+    prof = tmp_path / "prof"
+    with jax.profiler.trace(str(prof)):
+        sim.reset()
+        sim.run(save=False)
+    (xplane,) = prof.rglob("*.xplane.pb")
+    names = {"life.upload", "life.advance", "life.collect"}
+    found = [(e.start_ns, e.name)
+             for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name in names]
+    assert [n for _, n in sorted(found)] == ["life.upload", "life.advance",
+                                            "life.collect"]

@@ -8,6 +8,7 @@ match the ``2*(p-1)`` ring structure with the same engine stamp
 actually did, and the traced dispatch stays parity-exact.
 """
 
+import contextlib
 import json
 import math
 import time
@@ -123,6 +124,79 @@ def test_tracing_off_is_a_shared_noop(monkeypatch, tmp_path):
         s.set(x=1).anchor(None)
     trace.event("nothing")  # must not create a sink either
     assert list(tmp_path.iterdir()) == []
+
+
+class _Annotations:
+    """A recorder in place of ``jax.profiler.TraceAnnotation``."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kwargs):
+        log = self.log
+
+        class _One:
+            def __enter__(self):
+                log.append(("enter", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+                return False
+
+        return _One()
+
+
+@pytest.mark.parametrize("raises", [False, True])
+def test_live_span_is_a_profiler_annotation_of_its_name(sink, monkeypatch,
+                                                        raises):
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    with pytest.raises(ValueError) if raises else contextlib.nullcontext():
+        with trace.span("outer", attr=1):
+            with trace.span("inner"):
+                if raises:
+                    raise ValueError("boom")
+    assert rec.log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+    assert [r["name"] for r in _records(sink)] == ["inner", "outer"]
+
+
+def test_off_span_opens_no_annotation(monkeypatch):
+    monkeypatch.delenv("MOMP_TRACE", raising=False)
+    trace.reset()
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    with trace.span("anything") as sp:
+        sp.anchor(jnp.ones(4))
+    assert rec.log == []
+
+
+def test_anchor_blocks_and_fetches_nothing(sink, monkeypatch, sp_mesh):
+    """The anchor waits for the device work and nothing else: no
+    one-element probe comes back to the host, sharded or not."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharded = jax.device_put(jnp.arange(64.0),
+                             NamedSharding(sp_mesh, P("sp")))
+    blocked = []
+    block = jax.block_until_ready
+
+    def recorded(tree):
+        blocked.append(tree)
+        return block(tree)
+
+    def no_fetch(*_a, **_k):
+        raise AssertionError("the anchor fetched to the host")
+
+    monkeypatch.setattr(jax, "block_until_ready", recorded)
+    monkeypatch.setattr(jax, "device_get", no_fetch)
+    tree = (sharded * 2, jnp.ones(3))
+    with trace.span("anchored") as sp:
+        sp.anchor(tree)
+    assert blocked == [tree]
+    (rec,) = _records(sink)
+    assert rec["name"] == "anchored" and "error" not in rec
 
 
 def test_hop_spans_opt_out_env(sink, monkeypatch):
@@ -520,56 +594,6 @@ def test_chrome_cli_round_trip_on_real_trace(rng, sp_mesh, sink, tmp_path,
 
 
 # ------------------------------------------------------------------ profile
-
-
-@pytest.fixture(autouse=True)
-def _fresh_cost_cache():
-    profile.reset_cost_cache()
-    yield
-    profile.reset_cost_cache()
-
-
-def test_profile_cost_finite_and_memoised():
-    from mpi_and_open_mp_tpu.ops.life_ops import life_step_roll
-
-    spec = jax.ShapeDtypeStruct((64, 64), np.uint8)
-    got = profile.cost(life_step_roll, spec, name="life_step_roll")
-    assert got["flops"] > 0 and math.isfinite(got["flops"])
-    assert got["bytes"] > 0 and math.isfinite(got["bytes"])
-    assert got["compile_seconds"] > 0
-    assert got["argument_bytes"] == 64 * 64
-    assert metrics.get("profile.cost_cache", result="miss") == 1
-    # Same (name, shapes): served from the memo, no recompile.
-    again = profile.cost(life_step_roll, spec, name="life_step_roll")
-    assert again == got
-    assert metrics.get("profile.cost_cache", result="hit") == 1
-    hist = metrics.snapshot()["histograms"]
-    assert hist["profile.compile_seconds{fn=life_step_roll}"]["count"] == 1
-    # A different shape is a different artifact → a second miss.
-    profile.cost(life_step_roll, jax.ShapeDtypeStruct((32, 32), np.uint8),
-                 name="life_step_roll")
-    assert metrics.get("profile.cost_cache", result="miss") == 2
-
-
-def test_roofline_placement_and_bound():
-    rf = profile.roofline(1e6, 1e5, 1e-3, device_kind="TPU v5 lite")
-    assert rf["peaks"] == "v5 lite-table"
-    assert rf["flops_per_sec"] == pytest.approx(1e9)
-    assert rf["flops_pct"] == round(100 * 1e9 / 197e12, 3)
-    assert rf["bw_pct"] == round(100 * 1e8 / 819e9, 3)
-    # 0.012% bw > 0.0005% flops → the memory ceiling binds.
-    assert rf["bound"] == "memory"
-    assert rf["roofline_pct"] == rf["bw_pct"]
-    for v in rf.values():
-        if isinstance(v, float):
-            assert math.isfinite(v)
-    # Compute-bound case: tiny traffic, huge FLOPs.
-    assert profile.roofline(1e12, 1.0, 1e-3,
-                            device_kind="cpu")["bound"] == "compute"
-    with pytest.raises(ValueError):
-        profile.roofline(1.0, 1.0, 0.0)
-    with pytest.raises(ValueError):
-        profile.roofline(1.0, 1.0, float("nan"))
 
 
 def test_peaks_env_override(monkeypatch):
