@@ -59,7 +59,8 @@ The run loop preserves the reference's ordering (``3-life/life_mpi.c:51-62``):
 at step ``i``, save a snapshot when ``i % save_steps == 0`` (i.e. *before*
 stepping), then advance one step. Collect-to-host is ``jax.device_get`` of
 the sharded array — the ``MPI_Gather``/manual-recv-loop equivalent
-(``5-gather/life_mpi.c:178``, ``3-life/life_mpi.c:185-196``).
+(``5-gather/life_mpi.c:178``, ``3-life/life_mpi.c:185-196``); a Life board
+of 32 MiB or more crosses as bit-packed words (``LifeSim.collect``).
 
 Since the stencil subsystem (``mpi_and_open_mp_tpu.stencils``) landed,
 the sim is workload-generic: ``workload="life"`` (the default) is the
@@ -78,6 +79,7 @@ from __future__ import annotations
 import functools
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
 
 import numpy as np
@@ -166,6 +168,48 @@ def _note_retrace(fn: str) -> None:
     from mpi_and_open_mp_tpu.obs import metrics
 
     metrics.inc("jit.retrace", fn=fn)
+
+
+# The smallest board ``collect()`` packs. The pack costs one more dispatch
+# and device pass, and pays only where the host's write of the byte board
+# is dear: on a TPU v5e host, packing lost below 16 MiB (2.5 against
+# 1.6 ms at 4 MiB), broke even at 16 MiB and won at 64 MiB (32 against
+# 100 ms), where each fresh board is mapped and faulted in anew (glibc
+# serves arrays above 32 MiB from fresh mappings).
+_PACK_MIN_BYTES = 32 << 20
+
+# Output bytes per task of ``_unpack_words``.
+_UNPACK_CHUNK_BYTES = 1 << 20
+
+
+@functools.cache
+def _unpack_pool() -> ThreadPoolExecutor:
+    return ThreadPoolExecutor(os.cpu_count() or 1,
+                              thread_name_prefix="life-unpack")
+
+
+def _unpack_words(words: np.ndarray) -> np.ndarray:
+    """``uint32`` words ``(..., n)`` back to 0/1 bytes ``(..., 32 n)``:
+    bit ``j`` of word ``k`` becomes cell ``32 k + j``.
+
+    Writing a fresh board costs a page fault per page, and where faults
+    are dear (a gVisor-sandboxed TPU v5e host: 76 ms to fault 64 MiB,
+    4 ms to copy into pages already touched) they, not the unpacking,
+    take the time. So the rows unpack in chunks on a thread pool, which
+    spreads the faults over the host's cores (27 against 79 ms at 64 MiB
+    on that host's 13 cores).
+    """
+    src = np.ascontiguousarray(words, "<u4").view(np.uint8)
+    rows = src.reshape(-1, src.shape[-1])
+    out = np.empty((rows.shape[0], 8 * rows.shape[1]), np.uint8)
+    step = max(1, _UNPACK_CHUNK_BYTES // out.shape[1])
+
+    def unpack(i: int) -> None:
+        out[i: i + step] = np.unpackbits(rows[i: i + step], axis=-1,
+                                         bitorder="little")
+
+    list(_unpack_pool().map(unpack, range(0, rows.shape[0], step)))
+    return out.reshape(*words.shape[:-1], out.shape[-1])
 
 
 class LifeSim:
@@ -392,6 +436,7 @@ class LifeSim:
         self._run_id = 0
         self.reset()
         self._advance = self._build_advance()
+        self._pack = self._build_pack()
 
     # ---------------------------------------------------------- step builders
 
@@ -682,6 +727,39 @@ class LifeSim:
 
         return advance
 
+    def _build_pack(self) -> Callable[[jnp.ndarray], jnp.ndarray] | None:
+        """The program ``collect()`` packs the board with, or None where
+        it fetches bytes.
+
+        It packs 32 cells to a ``uint32`` along x: bit ``j`` of word ``k``
+        in row ``y`` holds cell ``(y, 32k + j)``, so ``(..., ny, nx)``
+        becomes ``(..., ny, nx // 32)`` under the board's own
+        ``PartitionSpec``, each shard packing its own cells with no
+        collective. It exists only where that is exact and pays: Life's
+        0/1 state (other rules carry more states, or floats), a board
+        this process addresses whole (multi-host boards gather bytes), a
+        shard width that is a multiple of 32, and a board of at least
+        ``_PACK_MIN_BYTES``.
+        """
+        board = self.board
+        if (self.workload != "life" or board.nbytes < _PACK_MIN_BYTES
+                or not board.sharding.is_fully_addressable):
+            return None
+        if board.sharding.shard_shape(board.shape)[-1] % 32:
+            return None
+
+        def pack(b):
+            w = b.astype(jnp.uint32).reshape(
+                *b.shape[:-1], b.shape[-1] // 32, 32)
+            return (w << jnp.arange(32, dtype=jnp.uint32)).sum(
+                -1, dtype=jnp.uint32)
+
+        if self.sharding is not None:
+            pack = jax.shard_map(pack, mesh=self.mesh,
+                                 in_specs=self.sharding.spec,
+                                 out_specs=self.sharding.spec)
+        return jax.jit(pack)
+
     # ------------------------------------------------------------ public API
 
     def step(self, n: int = 1) -> None:
@@ -800,7 +878,9 @@ class LifeSim:
         """
         from mpi_and_open_mp_tpu.stencils import parity_ok
 
-        before = self.collect()
+        # Bytes, not packed words: packing would fold a corrupt cell (a 2)
+        # into the 0/1 bits and hide it from the domain scan.
+        before = self._collect(packed=False)
         if not self.spec.valid_board(before):
             # Life/wireworld: out-of-range automaton state; float
             # stencils: non-finite cells. Either way the value invariant
@@ -970,20 +1050,42 @@ class LifeSim:
 
         for n in self._segment_lengths():
             anchor_sync(self._advance(self.board, n), fetch_all=True)
+        if self._pack is not None:
+            anchor_sync(self._pack(self.board), fetch_all=True)
 
     def collect(self) -> np.ndarray:
         """Gather the global board to the host (uint8 ``(ny, nx)``).
 
-        On multi-host (``jax.distributed``) runs the board is not fully
-        addressable from one process, so the gather goes through a
-        cross-process allgather — every host gets the full board, the
-        multi-host generalisation of the reference's gather-to-root
-        (``5-gather/life_mpi.c:178``). The fetch is synchronous, so the
-        span ``life.collect`` covers the transfer, the host's reordering
-        of the device layout and the crop.
+        Where ``_build_pack`` allows (Life, a board this process
+        addresses whole, a shard width that is a multiple of 32, a board
+        of at least ``_PACK_MIN_BYTES``), the device packs the board 32
+        cells to a ``uint32`` word and only the words cross to the host,
+        an eighth of the board's bytes, which ``np.unpackbits`` turns back
+        into the same 0/1 board in one pass (``_unpack_words``).
+        Elsewhere the board's bytes cross as they are. On multi-host
+        (``jax.distributed``) runs the board is not fully addressable from
+        one process, so the gather goes through a cross-process allgather
+        — every host gets the full board, the multi-host generalisation of
+        the reference's gather-to-root (``5-gather/life_mpi.c:178``). The
+        halo guard (``_consistency_violation``) always fetches bytes: a
+        packed word cannot show a cell that is neither 0 nor 1.
+
+        The fetch is synchronous, so the span ``life.collect`` covers the
+        pack, the transfer, the host's unpacking or reordering of the
+        device layout and the crop; it carries ``packed`` and
+        ``wire_bytes``, the bytes fetched to the host.
         """
+        return self._collect(packed=self._pack is not None)
+
+    def _collect(self, packed: bool) -> np.ndarray:
         with trace.span("life.collect", run=self._run_id,
-                        bytes=self.board.nbytes):
+                        bytes=self.board.nbytes, packed=packed) as sp:
+            if packed:
+                words = jax.device_get(self._pack(self.board))
+                sp.set(wire_bytes=words.nbytes)
+                full = _unpack_words(words)
+                return full[..., : self.cfg.ny, : self.cfg.nx]
+            sp.set(wire_bytes=self.board.nbytes)
             if self.board.is_fully_addressable:
                 full = np.asarray(
                     jax.device_get(self.board), dtype=self._np_dtype)

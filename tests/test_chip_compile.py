@@ -3,13 +3,16 @@
 Nothing runs: each test lowers and compiles for a chip that is described,
 not attached, and checks that the kernel is in the program
 (``tpu_custom_call``) or, for the sharded cart step, that the halo's
-``collective-permute`` is. What the chip's compiler refuses (unaligned
+``collective-permute`` is, and that the collect's packer holds no
+collective. What the chip's compiler refuses (unaligned
 slices, too much VMEM) fails here at no chip time. A compile that passes
 is not a chip run.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +121,28 @@ def test_rdma_edge_pair_compiles_on_2x2(mesh_2x2):
         sharding=NamedSharding(mesh_2x2, spec))
     text = jax.jit(pair).lower(edge, edge).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("where", ["one_chip", "mesh_2x2"])
+def test_collect_pack_compiles_without_collectives(request, where):
+    """``LifeSim.collect()``'s packer at 8192² as ``_build_pack`` builds
+    it, on one chip and sharded ``P("y", "x")`` over the 2x2 mesh: each
+    shard packs its own cells, so the program holds no collective."""
+    from mpi_and_open_mp_tpu.models.life import LifeSim
+
+    place = request.getfixturevalue(where)
+    mesh = place if where == "mesh_2x2" else None
+    sharding = NamedSharding(mesh, P("y", "x")) if mesh else place
+    sim = object.__new__(LifeSim)
+    sim.workload, sim.mesh = "life", mesh
+    sim.sharding = sharding if mesh else None
+    board = jax.ShapeDtypeStruct((8192, 8192), jnp.uint8, sharding=sharding)
+    sim.board = SimpleNamespace(shape=board.shape, sharding=sharding,
+                                nbytes=8192 * 8192)
+    compiled = sim._build_pack().lower(board).compile()
+    out = compiled.output_shardings.shard_shape((8192, 256))
+    assert out == ((4096, 128) if mesh else (8192, 256))
+    text = compiled.as_text()
+    for op in ("all-gather", "collective-permute", "all-to-all",
+               "all-reduce", "reduce-scatter"):
+        assert op not in text

@@ -382,3 +382,143 @@ def test_run_spans_lie_on_the_profiler_clock(make_board, tmp_path, sink):
              if e.name in names]
     assert [n for _, n in sorted(found)] == ["life.upload", "life.advance",
                                             "life.collect"]
+
+
+# -------------------------------------------------------- packed collect
+
+
+@pytest.fixture
+def pack_any_size(monkeypatch):
+    """Lets small test boards take the packed collect (real boards take
+    it from ``_PACK_MIN_BYTES``)."""
+    from mpi_and_open_mp_tpu.models import life
+
+    monkeypatch.setattr(life, "_PACK_MIN_BYTES", 0)
+
+
+_MESHES = {
+    "serial": lambda: None,
+    "cart2x2": lambda: mesh_lib.make_mesh_2d(2, 2),
+    "row4x1": lambda: mesh_lib.make_mesh_1d(4, axis="y"),
+    "col1x4": lambda: mesh_lib.make_mesh_1d(4, axis="x"),
+}
+
+
+@pytest.mark.parametrize("layout,mesh,shape,packed", [
+    ("serial", "serial", (40, 64), True),
+    ("serial", "serial", (40, 500), False),
+    ("cart", "cart2x2", (48, 128), True),
+    ("row", "row4x1", (48, 96), True),
+    ("col", "col1x4", (40, 256), True),
+    ("col", "col1x4", (40, 192), False),  # 48-cell shards
+    ("serial", "serial", (3, 33, 64), True),  # a batched stack
+    ("serial", "serial", (520, 2048), True),  # unpacked in two chunks
+])
+def test_packed_collect_is_the_byte_board(make_board, pack_any_size, layout,
+                                          mesh, shape, packed):
+    """collect() and run() return the byte fetch's board, bit for bit;
+    the pack engages exactly where each shard's width is a multiple of
+    32, and its sharded program holds no collective."""
+    ny, nx = shape[-2:]
+    board = (np.stack([make_board(ny, nx) for _ in range(shape[0])])
+             if len(shape) == 3 else make_board(ny, nx))
+    cfg = config_from_board(np.zeros((ny, nx), np.uint8), steps=6,
+                            save_steps=1000)
+    impl = "roll" if layout == "serial" else "halo"
+    sim = LifeSim(cfg, layout=layout, impl=impl, mesh=_MESHES[mesh](),
+                  initial_board=board)
+    assert (sim._pack is not None) is packed
+    start = sim.collect()
+    assert start.dtype == np.uint8 and start.shape == shape
+    np.testing.assert_array_equal(start, board)
+    got = sim.run(save=False)
+    np.testing.assert_array_equal(got, sim._collect(packed=False))
+    np.testing.assert_array_equal(got, oracle_n(board, 6)
+                                  if len(shape) == 2 else
+                                  np.stack([oracle_n(b, 6) for b in board]))
+    if packed and sim.sharding is not None:
+        hlo = sim._pack.lower(sim.board).compile().as_text()
+        for op in ("all-gather", "collective-permute", "all-to-all",
+                   "all-reduce"):
+            assert op not in hlo
+
+
+def test_warmup_compiles_the_pack(make_board, pack_any_size):
+    board = make_board(32, 64)
+    cfg = config_from_board(board, steps=3, save_steps=1000)
+    sim = LifeSim(cfg, layout="cart", impl="halo",
+                  mesh=mesh_lib.make_mesh_2d(2, 2))
+    sim.warmup()
+    assert sim._pack._cache_size() == 1
+    sim.reset()
+    sim.run(save=False)
+    assert sim._pack._cache_size() == 1
+
+
+def test_guard_sees_a_non_binary_cell_past_the_packed_collect(
+        make_board, pack_any_size):
+    """A 2 on the board folds into the packed words' bits, so the guard
+    fetches bytes: the cell is still reported as non-binary."""
+    board = make_board(32, 64)
+    cfg = config_from_board(board, steps=4, save_steps=1000)
+    sim = LifeSim(cfg, layout="cart", impl="halo",
+                  mesh=mesh_lib.make_mesh_2d(2, 2))
+    assert sim._pack is not None
+    bad = board.copy()
+    bad[3, 5] = 2
+    sim._set_board(bad, 0)
+    assert sim.collect().max() == 1  # what a packed probe would see
+    assert sim._consistency_violation() == "non-binary cells on the board"
+    with pytest.raises(AssertionError, match="non-binary"):
+        sim.debug_check()
+
+
+@pytest.mark.parametrize("nx,packed", [(64, True), (500, False)])
+def test_collect_span_says_what_crossed(make_board, sink, pack_any_size, nx,
+                                        packed):
+    """life.collect carries ``packed`` and ``wire_bytes``, the bytes
+    fetched to the host; ``bytes`` stays the board's."""
+    board = make_board(24, nx)
+    cfg = config_from_board(board, steps=2, save_steps=1000)
+    sim = LifeSim(cfg, layout="serial", impl="roll")
+    sink.write_text("")  # drop the constructor's upload
+    np.testing.assert_array_equal(sim.collect(), board)
+    (rec,) = _spans(sink)
+    assert rec["name"] == "life.collect"
+    attrs = rec["attrs"]
+    assert attrs["packed"] is packed
+    assert attrs["bytes"] == sim.board.nbytes == 24 * nx
+    assert attrs["wire_bytes"] == (sim.board.nbytes // 8 if packed
+                                   else sim.board.nbytes)
+
+
+@pytest.mark.parametrize("ny,packed", [(4096, True), (4095, False)])
+def test_pack_engages_from_min_bytes(ny, packed):
+    """Without the test override, a board packs from 32 MiB on: 4096 rows
+    of 8192 cells, but not 4095."""
+    from mpi_and_open_mp_tpu.models import life
+
+    assert life._PACK_MIN_BYTES == 4096 * 8192
+    board = np.zeros((ny, 8192), np.uint8)
+    board[ny - 1, 8191] = board[7, 40] = 1
+    cfg = config_from_board(board, steps=1, save_steps=1000)
+    sim = LifeSim(cfg, layout="serial", impl="roll")
+    assert (sim._pack is not None) is packed
+    np.testing.assert_array_equal(sim.collect(), board)
+
+
+@pytest.mark.parametrize("shape", [(5, 3), (64, 32), (2, 7, 4)])
+def test_unpack_words_in_chunks(rng, monkeypatch, shape):
+    """The pooled unpack is np.unpackbits over the words' little-endian
+    bytes, whatever the chunk size cuts the rows into."""
+    from mpi_and_open_mp_tpu.models import life
+
+    words = rng.integers(0, 2**32, shape, dtype=np.uint32)
+    want = np.unpackbits(words.astype("<u4").view(np.uint8), axis=-1,
+                         bitorder="little")
+    for chunk in (1, 3 * 32 * shape[-1], 1 << 20):
+        monkeypatch.setattr(life, "_UNPACK_CHUNK_BYTES", chunk)
+        got = life._unpack_words(words)
+        assert got.dtype == np.uint8 and got.shape == (*shape[:-1],
+                                                       32 * shape[-1])
+        np.testing.assert_array_equal(got, want)
