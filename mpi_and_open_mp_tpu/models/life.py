@@ -1114,8 +1114,8 @@ class LifeSim:
             if jax.process_index() == 0:
                 with trace.span("life.vtk_write", run=self._run_id) as sp:
                     os.makedirs(self.outdir, exist_ok=True)
-                    vtk_lib.write_vtk(path, board)
-                    sp.set(bytes=os.path.getsize(path))
+                    writer = vtk_lib.write_vtk(path, board)
+                    sp.set(bytes=os.path.getsize(path), writer=writer)
         return path
 
     def save_state(self) -> None:

@@ -20,15 +20,17 @@ def vtk_path(outdir: str | os.PathLike, step: int) -> str:
     return os.path.join(outdir, f"life_{step:06d}.vtk")
 
 
-def write_vtk(path: str | os.PathLike, board: np.ndarray) -> None:
-    """Write one board snapshot (native C writer when built, Python otherwise)."""
+def write_vtk(path: str | os.PathLike, board: np.ndarray) -> str:
+    """Write one board snapshot (native C writer when built, Python
+    otherwise); returns the writer that ran, ``"native"`` or ``"python"``."""
     from mpi_and_open_mp_tpu.utils import native
 
     board = np.asarray(board, dtype=np.int32)
     if native.available():
         native.write_vtk(path, board)
-        return
+        return "native"
     write_vtk_py(path, board)
+    return "python"
 
 
 def write_vtk_py(path: str | os.PathLike, board: np.ndarray) -> None:
