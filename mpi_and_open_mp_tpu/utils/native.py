@@ -1,8 +1,8 @@
-"""ctypes bindings to the native C++ runtime IO library (``native/``).
+"""ctypes bindings to the native C++ library (``native/``).
 
-The reference's runtime layer (config parsing + VTK serialisation,
-``/root/reference/3-life/life2d.c:52-102``) is compiled C; this framework
-keeps that layer native too: ``native/lifeio.cpp`` built as ``liblifeio.so``.
+``native/lifeio.cpp``, built as ``liblifeio.so``, holds the config
+parser (the reference's ``3-life/life2d.c:52-72`` loader) and two serial
+Life oracles. VTK frames are written by ``utils/vtk.py`` in NumPy.
 Python falls back transparently when the library hasn't been built
 (``make -C native``). Under a NON-editable install the repo-relative
 default can't resolve — set ``MOMP_NATIVE_LIB=/path/to/liblifeio.so``
@@ -63,13 +63,6 @@ def _load():
     ]
     lib.lifeio_free.restype = None
     lib.lifeio_free.argtypes = [ctypes.POINTER(ctypes.c_longlong)]
-    lib.lifeio_write_vtk.restype = ctypes.c_int
-    lib.lifeio_write_vtk.argtypes = [
-        ctypes.c_char_p,
-        ctypes.POINTER(ctypes.c_int),
-        ctypes.c_longlong,
-        ctypes.c_longlong,
-    ]
     lib.lifeio_life_steps.restype = None
     lib.lifeio_life_steps.argtypes = [
         ctypes.POINTER(ctypes.c_uint8),
@@ -138,16 +131,3 @@ def life_steps(board: np.ndarray, steps: int, bits: bool = False) -> np.ndarray:
     fn(out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), nx, ny, int(steps))
     return out
 
-
-def write_vtk(path, board: np.ndarray) -> None:
-    lib = _require()
-    board = np.ascontiguousarray(board, dtype=np.int32)
-    ny, nx = board.shape
-    rc = lib.lifeio_write_vtk(
-        str(path).encode(),
-        board.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
-        nx,
-        ny,
-    )
-    if rc != 0:
-        raise OSError(f"{path}: native VTK write failed (rc={rc})")

@@ -10,6 +10,7 @@ demand, files named ``life_%06d.vtk`` by step index.
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 
@@ -20,37 +21,61 @@ def vtk_path(outdir: str | os.PathLike, step: int) -> str:
     return os.path.join(outdir, f"life_{step:06d}.vtk")
 
 
-def write_vtk(path: str | os.PathLike, board: np.ndarray) -> str:
-    """Write one board snapshot (native C writer when built, Python
-    otherwise); returns the writer that ran, ``"native"`` or ``"python"``."""
-    from mpi_and_open_mp_tpu.utils import native
+@functools.lru_cache(maxsize=16)
+def _header(nx: int, ny: int) -> bytes:
+    return (
+        "# vtk DataFile Version 3.0\n"
+        "Created by mpi_and_open_mp_tpu\n"
+        "ASCII\n"
+        "DATASET STRUCTURED_POINTS\n"
+        f"DIMENSIONS {nx + 1} {ny + 1} 1\n"
+        "SPACING 1 1 0.0\n"
+        "ORIGIN 0 0 0.0\n"
+        f"CELL_DATA {nx * ny}\n"
+        "SCALARS life int 1\n"
+        "LOOKUP_TABLE life_table\n"
+    ).encode()
 
-    board = np.asarray(board, dtype=np.int32)
-    if native.available():
-        native.write_vtk(path, board)
-        return "native"
-    write_vtk_py(path, board)
-    return "python"
+
+def _one_digit(board: np.ndarray) -> bool:
+    """Every value prints as one digit (0..9)."""
+    return board.size > 0 and board.min() >= 0 and board.max() <= 9
+
+
+def write_vtk(path: str | os.PathLike, board: np.ndarray) -> str:
+    """Write one board snapshot; returns the path taken, ``"numpy"`` or
+    ``"python"``.
+
+    A board of one-digit values (every Life board) is a header and then
+    a digit byte and ``\n`` per cell: one vectorised pass fills that
+    buffer and one ``write()`` puts it on disk. Any other board takes
+    :func:`write_vtk_py`. Both give the same bytes.
+    """
+    board = np.asarray(board)
+    if board.dtype.kind not in "biu":
+        board = board.astype(np.int32)  # the values write_vtk_py prints
+    if not _one_digit(board):
+        write_vtk_py(path, board)
+        return "python"
+    ny, nx = board.shape
+    head = _header(nx, ny)
+    out = np.empty(len(head) + 2 * nx * ny, np.uint8)
+    out[: len(head)] = np.frombuffer(head, np.uint8)
+    cells = out[len(head):].reshape(ny, nx, 2)
+    np.add(board, ord("0"), out=cells[..., 0], casting="unsafe")
+    cells[..., 1] = ord("\n")
+    with open(path, "wb") as fd:
+        fd.write(out)
+    return "numpy"
 
 
 def write_vtk_py(path: str | os.PathLike, board: np.ndarray) -> None:
+    """The general writer: one ``str`` per cell, any integer values."""
     board = np.asarray(board, dtype=np.int32)
     ny, nx = board.shape
-    lines = [
-        "# vtk DataFile Version 3.0",
-        "Created by mpi_and_open_mp_tpu",
-        "ASCII",
-        "DATASET STRUCTURED_POINTS",
-        f"DIMENSIONS {nx + 1} {ny + 1} 1",
-        "SPACING 1 1 0.0",
-        "ORIGIN 0 0 0.0",
-        f"CELL_DATA {nx * ny}",
-        "SCALARS life int 1",
-        "LOOKUP_TABLE life_table",
-    ]
     body = "\n".join(str(v) for v in board.ravel())
-    with open(path, "w") as fd:
-        fd.write("\n".join(lines) + "\n" + body + "\n")
+    with open(path, "wb") as fd:
+        fd.write(_header(nx, ny) + (body + "\n").encode())
 
 
 _DIMS_RE = re.compile(r"DIMENSIONS\s+(\d+)\s+(\d+)\s+(\d+)")

@@ -1,11 +1,11 @@
-// Native runtime IO for mpi_and_open_mp_tpu: config parsing + VTK writing.
+// Native runtime for mpi_and_open_mp_tpu: config parsing and two serial
+// Life oracles.
 //
-// The reference's runtime layer is compiled C (cfg loader at
-// /root/reference/3-life/life2d.c:52-72, VTK writer at
-// 3-life/life_mpi.c:120-148); this framework keeps those host-side hot
-// paths native as well. Exposed as a plain C ABI for ctypes
+// The reference's cfg loader is compiled C (3-life/life2d.c:52-72); this
+// framework keeps it native as well, next to the oracles the device
+// kernels are checked against. Exposed as a plain C ABI for ctypes
 // (mpi_and_open_mp_tpu/utils/native.py). Built fresh for this project —
-// buffered IO instead of the reference's fscanf/fprintf-per-cell.
+// one buffered read instead of the reference's fscanf per token.
 //
 // Build: make -C native     (produces liblifeio.so)
 
@@ -67,39 +67,6 @@ int lifeio_load_config(const char *path, long long header[5],
 }
 
 void lifeio_free(long long *p) { std::free(p); }
-
-// Write an ASCII VTK 3.0 STRUCTURED_POINTS snapshot of a (ny, nx) board
-// (row-major int32), format-compatible with the reference's output
-// (header fields as at 3-life/life_mpi.c:129-140). Single buffered write.
-int lifeio_write_vtk(const char *path, const int *board, long long nx,
-                     long long ny) {
-    std::string out;
-    out.reserve(static_cast<size_t>(nx * ny * 2 + 256));
-    char header[256];
-    std::snprintf(header, sizeof header,
-                  "# vtk DataFile Version 3.0\n"
-                  "Created by mpi_and_open_mp_tpu\n"
-                  "ASCII\n"
-                  "DATASET STRUCTURED_POINTS\n"
-                  "DIMENSIONS %lld %lld 1\n"
-                  "SPACING 1 1 0.0\n"
-                  "ORIGIN 0 0 0.0\n"
-                  "CELL_DATA %lld\n"
-                  "SCALARS life int 1\n"
-                  "LOOKUP_TABLE life_table\n",
-                  nx + 1, ny + 1, nx * ny);
-    out += header;
-    char num[24];
-    for (long long k = 0; k < nx * ny; ++k) {
-        int n = std::snprintf(num, sizeof num, "%d\n", board[k]);
-        out.append(num, static_cast<size_t>(n));
-    }
-    FILE *fd = std::fopen(path, "wb");
-    if (!fd) return -1;
-    size_t wrote = std::fwrite(out.data(), 1, out.size(), fd);
-    std::fclose(fd);
-    return wrote == out.size() ? 0 : -2;
-}
 
 // Serial Game-of-Life oracle: advance a (ny, nx) uint8 board `steps`
 // generations on a periodic torus. Same role as the reference's compiled

@@ -7,7 +7,9 @@ any backend is initialised; ``jax_platforms`` is also pinned in-process so a
 caller that did not export ``JAX_PLATFORMS=cpu`` still gets the CPU mesh.
 """
 
+import importlib.util
 import os
+import sys
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
@@ -54,3 +56,18 @@ def oracle_n(board, n):
     for _ in range(n):
         b = life_step_numpy(b)
     return b
+
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def bench_module(name):
+    """``benchmark/<name>.py`` by its path: the benchmark's directory is
+    not put on ``sys.path``, where its ``tests`` would shadow these."""
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(BENCH, name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module

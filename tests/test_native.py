@@ -1,4 +1,4 @@
-"""Native C++ IO library: build, bind, and parity with the Python fallback."""
+"""Native C++ library: build, bind, and parity with the Python fallbacks."""
 
 import os
 import subprocess
@@ -8,7 +8,6 @@ import pytest
 
 from mpi_and_open_mp_tpu.utils import native
 from mpi_and_open_mp_tpu.utils.config import load_config_py, save_config, config_from_board
-from mpi_and_open_mp_tpu.utils.vtk import read_vtk, write_vtk_py
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
@@ -47,17 +46,6 @@ def test_native_load_errors(tmp_path):
         native.load_config(dangling)
     with pytest.raises(ValueError):
         native.load_config(tmp_path / "missing.cfg")
-
-
-def test_native_vtk_matches_python(tmp_path, make_board):
-    board = make_board(13, 21)
-    p_native = tmp_path / "native.vtk"
-    p_py = tmp_path / "py.vtk"
-    native.write_vtk(p_native, board.astype(np.int32))
-    write_vtk_py(p_py, board)
-    # Byte-identical output from both writers.
-    assert p_native.read_bytes() == p_py.read_bytes()
-    np.testing.assert_array_equal(read_vtk(p_native), board)
 
 
 def test_native_oracle_matches_numpy(make_board):

@@ -3,38 +3,21 @@ frame before each step ``i < steps``, each byte for byte the text of the
 benchmark's plain reference (``benchmark/reference_snap.py``) for the
 board at that step; the frame's write span names the writer that ran."""
 
-import importlib.util
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
 
 from mpi_and_open_mp_tpu.models.life import LifeSim
 from mpi_and_open_mp_tpu.parallel import mesh as mesh_lib
-from mpi_and_open_mp_tpu.utils import native
+from mpi_and_open_mp_tpu.utils import vtk
 from mpi_and_open_mp_tpu.utils.config import config_from_board
 
-from conftest import oracle_n
+from conftest import bench_module, oracle_n
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-
-
-def _bench_module(name):
-    """``benchmark/<name>.py`` by its path: the benchmark's directory is
-    not put on ``sys.path``, where its ``tests`` would shadow these."""
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(BENCH, name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
-_bench_module("reference")  # what reference_snap imports
-reference_snap = _bench_module("reference_snap")
+bench_module("reference")  # what reference_snap imports
+reference_snap = bench_module("reference_snap")
 
 STEPS = 30
 
@@ -64,20 +47,14 @@ def test_every_step_frame_is_the_reference_text(make_board, tmp_path, impl):
 
 @pytest.fixture
 def writer(request, monkeypatch):
-    """``native`` (skipped where ``native/liblifeio.so`` is not built) or
-    ``python`` (the library treated as absent)."""
-    if request.param == "native":
-        monkeypatch.setattr(native, "_LIB", None)
-        monkeypatch.setattr(native, "_TRIED", False)
-        if not native.available():
-            pytest.skip("native/liblifeio.so is not built")
-    else:
-        monkeypatch.setattr(native, "_LIB", None)
-        monkeypatch.setattr(native, "_TRIED", True)
+    """``numpy`` (a Life board, as it comes) or ``python`` (the
+    one-digit check made to refuse)."""
+    if request.param == "python":
+        monkeypatch.setattr(vtk, "_one_digit", lambda board: False)
     return request.param
 
 
-@pytest.mark.parametrize("writer", ["native", "python"], indirect=True)
+@pytest.mark.parametrize("writer", ["numpy", "python"], indirect=True)
 def test_vtk_write_span_names_its_writer(make_board, tmp_path, monkeypatch,
                                          writer):
     from mpi_and_open_mp_tpu.obs import trace

@@ -11,7 +11,7 @@ from mpi_and_open_mp_tpu.utils.config import (
     load_config_py,
     save_config,
 )
-from mpi_and_open_mp_tpu.utils.vtk import read_vtk, write_vtk_py
+from mpi_and_open_mp_tpu.utils.vtk import read_vtk, write_vtk, write_vtk_py
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 
@@ -57,28 +57,16 @@ def test_vtk_golden_file(tmp_path):
     `4-life/vtk/life_000000.vtk` artifact): the writer's byte-level
     output for the glider fixture is pinned, so any format drift —
     header, ordering, line endings — fails here even when the reference
-    tree is absent. Both writers (Python and, when built, the native
-    C++ one) must reproduce it exactly, and the reader must invert it."""
+    tree is absent. Both paths (the one-pass default and the general
+    writer) must reproduce it exactly, and the reader must invert it."""
     golden = os.path.join(FIXTURES, "golden_glider_000000.vtk")
     cfg = load_config_py(os.path.join(FIXTURES, "glider_10x10.cfg"))
     np.testing.assert_array_equal(read_vtk(golden), cfg.board())
 
-    ours = tmp_path / "life_000000.vtk"
-    write_vtk_py(ours, cfg.board())
-    assert ours.read_text() == open(golden).read()
-
-    from mpi_and_open_mp_tpu.utils import native
-
-    if native.available():
-        theirs = tmp_path / "life_native.vtk"
-        native.write_vtk(theirs, cfg.board())
-        got = theirs.read_text().splitlines()
-        want = open(golden).read().splitlines()
-        assert len(got) == len(want)
-        for i, (g, w) in enumerate(zip(got, want)):
-            if i == 1:  # creator comment line may differ
-                continue
-            assert g == w, f"line {i}: {g!r} != {w!r}"
+    for write in (write_vtk, write_vtk_py):
+        ours = tmp_path / f"{write.__name__}.vtk"
+        write(ours, cfg.board())
+        assert ours.read_bytes() == open(golden, "rb").read(), write.__name__
 
 
 @pytest.mark.parametrize("n,p", [(500, 8), (10, 3), (28, 28), (7, 2), (100, 1)])
@@ -192,15 +180,16 @@ def test_vtk_golden_cross_compat_with_reference_artifact(tmp_path):
     board = read_vtk(ref_path)
     np.testing.assert_array_equal(board, cfg.board())
 
-    ours = tmp_path / "life_000000.vtk"
-    write_vtk_py(ours, board)
-    got = ours.read_text().splitlines()
     want = open(ref_path).read().splitlines()
-    assert len(got) == len(want)
-    for i, (g, w) in enumerate(zip(got, want)):
-        if i == 1:  # creator comment line differs by design
-            continue
-        assert g == w, f"line {i}: {g!r} != {w!r}"
+    for write in (write_vtk, write_vtk_py):
+        ours = tmp_path / f"{write.__name__}.vtk"
+        write(ours, board)
+        got = ours.read_text().splitlines()
+        assert len(got) == len(want)
+        for i, (g, w) in enumerate(zip(got, want)):
+            if i == 1:  # creator comment line differs by design
+                continue
+            assert g == w, f"{write.__name__} line {i}: {g!r} != {w!r}"
 
 
 def test_config_cells_wrap_like_reference_ind_macro(tmp_path):
