@@ -175,7 +175,7 @@ WATCH_FIELDS = (
     "telemetry_snapshot_loss_frac",
     "loadgen_burn_rate_peak",
     # Wide-radius engine families (PR 20): per-family steady rates from
-    # the bench --radius-ab crossover sweep, recorded at the widest
+    # the radius crossover sweep, recorded at the widest
     # parity-clean radius measured (higher by the cups rule), plus the
     # best family-vs-offset ratio over the radius >= 8 cells (higher by
     # default — the ratio is same-process, RTT- and noise-cancelled

@@ -62,16 +62,9 @@ the sharded array — the ``MPI_Gather``/manual-recv-loop equivalent
 (``5-gather/life_mpi.c:178``, ``3-life/life_mpi.c:185-196``); a Life board
 of 32 MiB or more crosses as bit-packed words (``LifeSim.collect``).
 
-Since the stencil subsystem (``mpi_and_open_mp_tpu.stencils``) landed,
-the sim is workload-generic: ``workload="life"`` (the default) is the
-historical behaviour bit-for-bit, while any other registered
-:class:`~mpi_and_open_mp_tpu.stencils.StencilSpec` (heat, gray_scott,
-wireworld, ...) runs through the SAME roll / halo / generic-Pallas
-machinery — spec dtype, spec oracle, spec domain check, channel axes
-riding in front of the sharded board axes. The bit-packed engines
-(``bitfused`` and the batched native dispatch) encode Life's 0/1 state
-specifically, so they stay ``life``-only; ``impl="auto"`` for other
-workloads picks ``halo`` when the board divides the mesh, else ``roll``.
+The board is Life's 0/1 ``uint8`` state on every path. Other stencil
+rules have their own entry (``stencils.engine``: ``run_roll``,
+``run_sharded``).
 """
 
 from __future__ import annotations
@@ -104,19 +97,13 @@ IMPLS = ("auto", "roll", "halo", "pallas", "bitfused")
 _BITFUSED_1DEV_SERIAL_ON_CPU = False
 
 
-def _layout_spec(layout: str, channels: int = 1) -> P:
-    axes = {
+def _layout_spec(layout: str) -> P:
+    return P(*{
         "serial": (),
         "row": ("y", None),
         "col": (None, "x"),
         "cart": ("y", "x"),
-    }[layout]
-    # Multi-channel stencils carry the channel axis in FRONT of the board
-    # axes; it is never sharded (every device owns all fields of its
-    # cells, the layout that keeps the update local).
-    if channels > 1 and axes:
-        axes = (None, *axes)
-    return P(*axes)
+    }[layout])
 
 
 def _default_mesh(layout: str) -> Mesh | None:
@@ -148,15 +135,11 @@ def _ceil_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _oracle_step(board: np.ndarray, spec) -> np.ndarray:
-    """One NumPy-oracle step of ``spec``; for single-channel specs a
-    (B, ny, nx) stack steps per board (a multi-channel 3D array IS one
-    board — channels lead, there is no batched multi-channel mode)."""
-    from mpi_and_open_mp_tpu.stencils import step_numpy
-
-    if spec.channels == 1 and board.ndim == 3:
-        return np.stack([step_numpy(spec, b) for b in board])
-    return step_numpy(spec, board)
+def _oracle_step(board: np.ndarray) -> np.ndarray:
+    """One NumPy-oracle step; a (B, ny, nx) stack steps per board."""
+    if board.ndim == 3:
+        return np.stack([life_ops.life_step_numpy(b) for b in board])
+    return life_ops.life_step_numpy(board)
 
 
 def _note_retrace(fn: str) -> None:
@@ -236,41 +219,16 @@ class LifeSim:
         impl: str = "auto",
         mesh: Mesh | None = None,
         fuse_steps: int = 1,
-        dtype=None,
         outdir: str | os.PathLike | None = None,
         checkpoint_dir: str | os.PathLike | None = None,
         checkpoint_every: int = 0,
         initial_board: np.ndarray | None = None,
         initial_step: int = 0,
-        workload: str = "life",
     ):
-        from mpi_and_open_mp_tpu import stencils
-
         if layout not in LAYOUTS:
             raise ValueError(f"layout must be one of {LAYOUTS}, got {layout!r}")
         if impl not in IMPLS:
             raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
-        self.workload = str(workload)
-        self.spec = stencils.get(self.workload)
-        if dtype is None:
-            # Historical default for life (uint8) IS the spec dtype, so
-            # the pre-workload constructor signature is unchanged in
-            # behaviour; other specs bring their own cell dtype.
-            dtype = jnp.dtype(self.spec.dtype)
-        self._np_dtype = self.spec.np_dtype
-        if self.workload != "life":
-            # The bit-packed engines encode Life's 0/1 state; everything
-            # else runs the generic roll / halo / generic-Pallas paths.
-            if impl == "bitfused":
-                raise ValueError(
-                    f"impl='bitfused' is a bit-packed Life engine; "
-                    f"workload={self.workload!r} runs 'roll', 'halo' or "
-                    "'pallas' (sharded)")
-            if impl == "pallas" and layout == "serial":
-                raise ValueError(
-                    "serial impl='pallas' dispatches the bit-packed Life "
-                    f"VMEM engine; workload={self.workload!r} uses "
-                    "impl='roll' (serial) or 'pallas' on a sharded layout")
         # Batched mode: a STACKED (B, ny, nx) initial board advances all B
         # independent boards per dispatch through the batched native
         # engines (ops.pallas_life.life_run_vmem_batch) — the model-layer
@@ -279,17 +237,7 @@ class LifeSim:
         # not one mesh program), and no VTK/checkpoint channels (both
         # serialise ONE board; batched runs are throughput runs).
         self.batch: int | None = None
-        if (initial_board is not None
-                and np.asarray(initial_board).ndim
-                == 3 + (self.spec.channels > 1)):
-            if self.spec.channels > 1 or self.workload != "life":
-                # A 3D multi-channel array is ONE board (channels lead);
-                # stacks of non-life boards are the serve layer's
-                # bucketing problem, not a model-layer mode — the batched
-                # native engines are bit-packed Life.
-                raise ValueError(
-                    f"workload={self.workload!r} has no batched mode; "
-                    "submit stacks through the serve batcher instead")
+        if initial_board is not None and np.asarray(initial_board).ndim == 3:
             if layout != "serial":
                 raise ValueError(
                     "stacked (B, ny, nx) boards need layout='serial'; "
@@ -310,7 +258,6 @@ class LifeSim:
         self.layout = layout
         self.mesh = mesh if mesh is not None else _default_mesh(layout)
         self.fuse_steps = max(1, int(fuse_steps))
-        self.dtype = dtype
         self.outdir = os.fspath(outdir) if outdir is not None else None
         self.checkpoint_dir = (
             os.fspath(checkpoint_dir) if checkpoint_dir is not None else None
@@ -326,14 +273,10 @@ class LifeSim:
         divisible = _divisible(cfg.shape, layout, self.mesh)
         plan = (
             self._bitfused_plan(layout, cfg.shape)
-            if impl in ("auto", "bitfused") and self.workload == "life"
+            if impl in ("auto", "bitfused")
             else None
         )
-        if impl == "auto" and self.workload != "life":
-            # Generic-spec auto: the explicit-halo shard_map path when
-            # the board divides the mesh, else the global roll step.
-            impl = "halo" if (layout != "serial" and divisible) else "roll"
-        elif impl == "auto":
+        if impl == "auto":
             on_tpu = jax.default_backend() == "tpu"
             if self.batch is not None:
                 # The batched dispatcher compiles on EVERY backend (off-TPU
@@ -385,16 +328,15 @@ class LifeSim:
         if impl in ("halo", "pallas") and layout != "serial":
             py, px = _mesh_divisors(layout, self.mesh)
             local = min(cfg.ny // py, cfg.nx // px)
-            if self.fuse_steps * self.spec.radius > local:
+            if self.fuse_steps > local:
                 raise ValueError(
-                    f"fuse_steps={self.fuse_steps} x radius "
-                    f"{self.spec.radius} exceeds the smallest local shard "
-                    f"extent ({local}); a halo cannot be deeper than the "
-                    f"shard it pads"
+                    f"fuse_steps={self.fuse_steps} exceeds the smallest "
+                    f"local shard extent ({local}); a halo cannot be "
+                    f"deeper than the shard it pads"
                 )
 
         self.sharding = (
-            NamedSharding(self.mesh, _layout_spec(layout, self.spec.channels))
+            NamedSharding(self.mesh, _layout_spec(layout))
             if self.mesh is not None
             else None
         )
@@ -409,25 +351,20 @@ class LifeSim:
             py, px = _mesh_divisors(layout, self.mesh)
             self.padded_shape = (_ceil_to(cfg.ny, py), _ceil_to(cfg.nx, px))
         if initial_board is not None:
-            board = np.asarray(initial_board, dtype=self._np_dtype)
+            board = np.asarray(initial_board, dtype=np.uint8)
             expect = (
                 (self.batch, *cfg.shape) if self.batch is not None
-                else self.spec.board_shape(*cfg.shape)
+                else cfg.shape
             )
             if board.shape != expect:
                 raise ValueError(
                     f"initial_board {board.shape} != expected {expect}"
                 )
-        elif self.workload == "life":
-            board = cfg.board()
         else:
-            # Non-life boards come from the spec's own initialiser (the
-            # LifeConfig cell list encodes Life patterns specifically).
-            board = self.spec.init(np.random.default_rng(0xD1CE), cfg.shape)
+            board = cfg.board()
         if self.batch is None and self.padded_shape != cfg.shape:
-            full = np.zeros(
-                self.spec.board_shape(*self.padded_shape), dtype=board.dtype)
-            full[..., : cfg.ny, : cfg.nx] = board
+            full = np.zeros(self.padded_shape, dtype=board.dtype)
+            full[: cfg.ny, : cfg.nx] = board
             board = full
         self._initial = board
         self._initial_step = int(initial_step)
@@ -447,12 +384,12 @@ class LifeSim:
         return haloplan.plan_halo(
             self.layout, (py, px),
             (self.padded_shape[0] // py, self.padded_shape[1] // px),
-            self.spec.radius, k, channels=self.spec.channels,
+            1, k,
         )
 
     def _local_fused_step(self, block: jnp.ndarray, k: int) -> jnp.ndarray:
-        """One fused round of ``k`` local steps (each consuming
-        ``radius`` halo cells per side), scheduled by the persistent
+        """One fused round of ``k`` local steps (each consuming one
+        halo cell per side), scheduled by the persistent
         halo plan: ghost ``ppermute``s overlap the interior stencil when
         the geometry allows (``parallel.haloplan``), else the historic
         blocking ``halo_pad_*`` concat."""
@@ -463,12 +400,8 @@ class LifeSim:
         if self.impl == "pallas":
             from mpi_and_open_mp_tpu.ops import pallas_life
 
-            if self.workload == "life":
-                return pallas_life.life_step_padded_pallas(padded)
-            return pallas_life.stencil_step_padded_pallas(self.spec, padded)
-        from mpi_and_open_mp_tpu.stencils import step_padded
-
-        return step_padded(self.spec, padded, jnp)
+            return pallas_life.life_step_padded_pallas(padded)
+        return life_ops.life_step_padded(padded)
 
     def _build_advance(self) -> Callable[[jnp.ndarray, int], jnp.ndarray]:
         """Return ``advance(board, n)`` running ``n`` steps, jit-cached on ``n``."""
@@ -489,14 +422,10 @@ class LifeSim:
             return advance
 
         if self.impl == "roll" or self.layout == "serial":
-            from mpi_and_open_mp_tpu.stencils import step_roll
-
             sharding = self.sharding
-            spec_ = self.spec
             ny, nx = self.cfg.shape
             pad_y = self.padded_shape[0] - ny
             pad_x = self.padded_shape[1] - nx
-            lead = ((0, 0),) if spec_.channels > 1 else ()
 
             @functools.partial(jax.jit, static_argnums=1)
             def advance(board, n):
@@ -504,10 +433,10 @@ class LifeSim:
 
                 def body(_, b):
                     if pad_y or pad_x:
-                        v = step_roll(spec_, b[..., :ny, :nx], jnp)
-                        b = jnp.pad(v, (*lead, (0, pad_y), (0, pad_x)))
+                        v = life_ops.life_step_roll(b[..., :ny, :nx])
+                        b = jnp.pad(v, ((0, pad_y), (0, pad_x)))
                     else:
-                        b = step_roll(spec_, b, jnp)
+                        b = life_ops.life_step_roll(b)
                     if sharding is not None:
                         b = lax.with_sharding_constraint(b, sharding)
                     return b
@@ -518,7 +447,7 @@ class LifeSim:
             return advance
 
         # shard_map halo/pallas path, with k-step fusion per exchange round.
-        spec = _layout_spec(self.layout, self.spec.channels)
+        spec = _layout_spec(self.layout)
         k = self.fuse_steps
         # Provenance: the persistent plan's schedule stamp for the main
         # round depth ("overlap:*" when the ghost exchange hides behind
@@ -610,7 +539,6 @@ class LifeSim:
         mesh = self.mesh
         spec = _layout_spec(self.layout)
         interpret = jax.default_backend() != "tpu"
-        dtype = self.dtype
 
         if mesh.size == 1 and (not interpret
                                or _BITFUSED_1DEV_SERIAL_ON_CPU):
@@ -641,7 +569,7 @@ class LifeSim:
                     out = life_run_vmem(board[:ny, :nx], jnp.int32(n))
                     out = jnp.pad(out, ((0, fy - ny), (0, fx - nx)))
                 return lax.with_sharding_constraint(
-                    out.astype(dtype), self.sharding)
+                    out.astype(jnp.uint8), self.sharding)
 
             return advance
 
@@ -709,7 +637,7 @@ class LifeSim:
             q, _ = lax.while_loop(
                 lambda c: c[1] > 0, body, (packed, jnp.int32(n))
             )
-            return bitlife.unpack_board_exact(q).astype(dtype)
+            return bitlife.unpack_board_exact(q).astype(jnp.uint8)
 
         smapped = jax.shard_map(
             shard_fn,
@@ -735,14 +663,13 @@ class LifeSim:
         in row ``y`` holds cell ``(y, 32k + j)``, so ``(..., ny, nx)``
         becomes ``(..., ny, nx // 32)`` under the board's own
         ``PartitionSpec``, each shard packing its own cells with no
-        collective. It exists only where that is exact and pays: Life's
-        0/1 state (other rules carry more states, or floats), a board
-        this process addresses whole (multi-host boards gather bytes), a
-        shard width that is a multiple of 32, and a board of at least
+        collective. It exists only where it pays: a board this process
+        addresses whole (multi-host boards gather bytes), a shard width
+        that is a multiple of 32, and a board of at least
         ``_PACK_MIN_BYTES``.
         """
         board = self.board
-        if (self.workload != "life" or board.nbytes < _PACK_MIN_BYTES
+        if (board.nbytes < _PACK_MIN_BYTES
                 or not board.sharding.is_fully_addressable):
             return None
         if board.sharding.shard_shape(board.shape)[-1] % 32:
@@ -791,7 +718,7 @@ class LifeSim:
         """
         self._run_id += 1
         with trace.span("life.upload", run=self._run_id) as sp:
-            board = jnp.asarray(self._initial, dtype=self.dtype)
+            board = jnp.asarray(self._initial, dtype=jnp.uint8)
             self.board = (
                 jax.device_put(board, self.sharding) if self.sharding
                 else board
@@ -876,24 +803,16 @@ class LifeSim:
         hooks as the segment program (faults are sticky at trace time), so
         a poisoned exchange cannot hide from the probe.
         """
-        from mpi_and_open_mp_tpu.stencils import parity_ok
-
         # Bytes, not packed words: packing would fold a corrupt cell (a 2)
         # into the 0/1 bits and hide it from the domain scan.
         before = self._collect(packed=False)
-        if not self.spec.valid_board(before):
-            # Life/wireworld: out-of-range automaton state; float
-            # stencils: non-finite cells. Either way the value invariant
-            # broke before the step-parity probe even ran.
-            return ("out-of-domain cells on the board"
-                    if self.workload != "life"
-                    else "non-binary cells on the board")
+        if not np.isin(before, (0, 1)).all():
+            return "non-binary cells on the board"
         after_impl = np.asarray(
-            jax.device_get(self._advance(self.board, 1)),
-            dtype=self._np_dtype,
+            jax.device_get(self._advance(self.board, 1)), dtype=np.uint8,
         )[..., : self.cfg.ny, : self.cfg.nx]
-        expect = _oracle_step(before, self.spec)
-        if not parity_ok(self.spec, after_impl, expect):
+        expect = _oracle_step(before)
+        if not np.array_equal(after_impl, expect):
             if self.batch is not None:
                 # PER-BOARD honesty: name every diverging board of the
                 # stack, not just "the batch diverged".
@@ -919,9 +838,9 @@ class LifeSim:
         # with near-certainty.
         probe, probe_expect = self._probe_case()
         after_probe = np.asarray(
-            jax.device_get(self._advance(probe, 1)), dtype=self._np_dtype
+            jax.device_get(self._advance(probe, 1)), dtype=np.uint8
         )[..., : self.cfg.ny, : self.cfg.nx]
-        if not parity_ok(self.spec, after_probe, probe_expect):
+        if not np.array_equal(after_probe, probe_expect):
             diff = int((after_probe != probe_expect).sum())
             return (
                 f"{diff} cells diverge from the oracle after one "
@@ -934,30 +853,21 @@ class LifeSim:
         ``_consistency_violation`` — placed exactly like the live board."""
         if self._probe is None:
             rng = np.random.default_rng(0xC0FFEE)
-            if self.workload == "life":
-                shape = (self.cfg.ny, self.cfg.nx)
-                if self.batch is not None:
-                    # B DISTINCT dense boards (one rng stream): a fault
-                    # that corrupts only some stack positions must still
-                    # perturb the board that sits there.
-                    shape = (self.batch, *shape)
-                host = rng.integers(0, 2, shape, dtype=np.uint8)
-            else:
-                # The spec's own initialiser is the dense-enough probe
-                # state for non-life rules (batched mode is life-only).
-                host = np.asarray(
-                    self.spec.init(rng, self.cfg.shape),
-                    dtype=self._np_dtype)
+            shape = (self.cfg.ny, self.cfg.nx)
+            if self.batch is not None:
+                # B DISTINCT dense boards (one rng stream): a fault that
+                # corrupts only some stack positions must still perturb
+                # the board that sits there.
+                shape = (self.batch, *shape)
+            host = rng.integers(0, 2, shape, dtype=np.uint8)
             if self.batch is None and self.padded_shape != self.cfg.shape:
-                full = np.zeros(
-                    self.spec.board_shape(*self.padded_shape),
-                    dtype=self._np_dtype)
-                full[..., : self.cfg.ny, : self.cfg.nx] = host
+                full = np.zeros(self.padded_shape, dtype=np.uint8)
+                full[: self.cfg.ny, : self.cfg.nx] = host
             else:
                 full = host
-            b = jnp.asarray(full, dtype=self.dtype)
+            b = jnp.asarray(full, dtype=jnp.uint8)
             b = jax.device_put(b, self.sharding) if self.sharding else b
-            self._probe = (b, _oracle_step(host, self.spec))
+            self._probe = (b, _oracle_step(host))
         return self._probe
 
     def debug_check(self) -> None:
@@ -978,15 +888,13 @@ class LifeSim:
     def _set_board(self, board: np.ndarray, step: int) -> None:
         """Install a host board as the live state (pad + device_put), the
         same placement the constructor performs."""
-        board = np.asarray(board, dtype=self._np_dtype)
+        board = np.asarray(board, dtype=np.uint8)
         if (self.batch is None
                 and board.shape[-2:] != tuple(self.padded_shape)):
-            full = np.zeros(
-                self.spec.board_shape(*self.padded_shape),
-                dtype=self._np_dtype)
-            full[..., : self.cfg.ny, : self.cfg.nx] = board
+            full = np.zeros(self.padded_shape, dtype=np.uint8)
+            full[: self.cfg.ny, : self.cfg.nx] = board
             board = full
-        b = jnp.asarray(board, dtype=self.dtype)
+        b = jnp.asarray(board, dtype=jnp.uint8)
         self.board = jax.device_put(b, self.sharding) if self.sharding else b
         self.step_count = int(step)
 
@@ -1026,10 +934,10 @@ class LifeSim:
             self.recoveries.append(f"{stamp} ({why})")
             guards.record_recovery(stamp)
             return
-        board = np.asarray(jax.device_get(prev_board), dtype=self._np_dtype)[
+        board = np.asarray(jax.device_get(prev_board), dtype=np.uint8)[
             ..., : self.cfg.ny, : self.cfg.nx]
         for _ in range(n):
-            board = _oracle_step(board, self.spec)
+            board = _oracle_step(board)
         self._set_board(board, prev_step + n)
         stamp = "life_step:numpy-oracle:recovered"
         self.recoveries.append(f"{stamp} ({why}; then {still})")
@@ -1087,15 +995,14 @@ class LifeSim:
                 return full[..., : self.cfg.ny, : self.cfg.nx]
             sp.set(wire_bytes=self.board.nbytes)
             if self.board.is_fully_addressable:
-                full = np.asarray(
-                    jax.device_get(self.board), dtype=self._np_dtype)
+                full = np.asarray(jax.device_get(self.board), dtype=np.uint8)
             else:
                 from jax.experimental import multihost_utils
 
                 full = np.asarray(
                     multihost_utils.process_allgather(
                         self.board, tiled=True),
-                    dtype=self._np_dtype,
+                    dtype=np.uint8,
                 )
             # Ellipsis crop: batched boards are (B, ny, nx), the crop
             # applies to the trailing board axes either way.

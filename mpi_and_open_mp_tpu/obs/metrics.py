@@ -162,14 +162,11 @@ def snapshot() -> dict:
 
 def delta(before: dict, after: dict) -> dict:
     """The registry movement BETWEEN two :func:`snapshot` calls, in
-    snapshot shape — per-phase metric scoping for ``bench.py``: each
-    opt-in phase snapshots at entry and publishes only what IT moved,
-    so ``--batch`` counters cannot bleed into the ``--serve`` /
-    ``--loadgen`` sub-objects. Counters and histogram count/total
-    subtract (zero movement drops out); gauges are last-value-wins so
-    the phase reports those it TOUCHED at their ``after`` value;
-    histogram min/max cannot be un-merged and honestly report the
-    window's ``after`` values only when the count moved."""
+    snapshot shape: what one stretch of work moved. Counters and
+    histogram count/total subtract (zero movement drops out); gauges are
+    last-value-wins so the window reports those it TOUCHED at their
+    ``after`` value; histogram min/max cannot be un-merged and honestly
+    report the window's ``after`` values only when the count moved."""
     out: dict = {"counters": {}, "gauges": {}, "histograms": {}}
     b, a = before.get("counters", {}), after.get("counters", {})
     for key, v in a.items():
@@ -193,7 +190,7 @@ def delta(before: dict, after: dict) -> dict:
 
 
 def reset() -> None:
-    """Empty the registry (tests; fresh bench phases)."""
+    """Empty the registry (tests)."""
     with _LOCK:
         _COUNTERS.clear()
         _GAUGES.clear()

@@ -119,10 +119,10 @@ def _attention(records: list[dict]) -> dict:
 def _halo(records: list[dict]) -> dict:
     """The sharded halo-schedule summary: ``halo.overlap``/``halo.seq``
     span counts with the engine stamps seen on each, plus the exposed-
-    vs-hidden transfer accounting from the LAST ``halo.ab`` event
-    (``bench._sharded_ab_phase`` emits one per A/B: measured transfer
-    seconds per round, the exposed remainder the overlap failed to hide,
-    and their ratio as overlap efficiency)."""
+    vs-hidden transfer accounting from the LAST ``halo.ab`` event (one
+    per overlap-vs-sequential A/B: measured transfer seconds per round,
+    the exposed remainder the overlap failed to hide, and their ratio as
+    overlap efficiency)."""
     overlap = _spans(records, "halo.overlap")
     seq = _spans(records, "halo.seq")
     engines = sorted({(s.get("attrs") or {}).get("engine", "?")

@@ -4,11 +4,11 @@ Two deployments of the same :class:`~mpi_and_open_mp_tpu.serve.router.
 FleetRouter` contract:
 
 * :class:`Fleet` — N in-process :class:`ServingDaemon` workers sharing
-  one injectable clock. This is what ``bench.py --serve --fleet N`` and
-  the unit tests drive: deterministic, no subprocess spawn tax, wedges
-  simulated by halting a worker's pump (its heartbeat stops, the router
-  declares it, the WAL replay + re-home ladder runs for real against
-  the worker's real journal).
+  one injectable clock. This is what the unit tests drive:
+  deterministic, no subprocess spawn tax, wedges simulated by halting a
+  worker's pump (its heartbeat stops, the router declares it, the WAL
+  replay + re-home ladder runs for real against the worker's real
+  journal).
 * The module CLI (``python -m mpi_and_open_mp_tpu.serve.fleet``) — the
   cross-process deployment CI's ``fleet-chaos-smoke`` kills for real: a
   parent partitions a seeded burst by consistent hash, writes one spool
@@ -657,7 +657,7 @@ def main(argv=None) -> int:
             f"--workers {args.workers}: on an accelerator each worker "
             "process holds every chip of the host, so the fleet runs one "
             "worker; set JAX_PLATFORMS=cpu for a multi-worker CPU fleet, "
-            "or use the in-process Fleet (bench.py --serve N --fleet W)")
+            "or use the in-process serve.fleet.Fleet")
 
     from mpi_and_open_mp_tpu.serve.router import (
         ConsistentHashRing, affinity_key)

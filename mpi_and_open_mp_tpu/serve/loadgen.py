@@ -22,7 +22,7 @@ judges. One run yields a :class:`LoadgenReport` (goodput + nearest-rank
 p50/p99/p999 + shed breakdown + the fleet books); :func:`sweep` runs a
 monotone offered-load ladder on fresh fleets and :func:`saturation_knee`
 reads off the last rung that still meets the :class:`SLO` — the
-capacity number ``bench.py --loadgen`` publishes.
+fleet's capacity number.
 
 Traffic is a :class:`ScenarioMix`, because a fleet that only ever sees
 one-shot same-shape tickets is not under real load: the mix weights

@@ -11,9 +11,9 @@ decision (``<digest>.plan``) and its compiled form (``<digest>.aot``)
 side by side, with the same corrupt/stale quarantine-and-rebuild
 semantics.
 
-Runtime knobs: ``MOMP_TUNE_PLANS`` points daemons/bench at a store
-directory; ``MOMP_TUNE=0`` is the kill switch (heuristics only, plans
-ignored untouched).
+Runtime knobs: ``MOMP_TUNE_PLANS`` points the daemons and ``apps.life``
+at a store directory; ``MOMP_TUNE=0`` is the kill switch (heuristics
+only, plans ignored untouched).
 """
 
 from .plans import (  # noqa: F401

@@ -49,7 +49,7 @@ def tune(workload: str, shape, *, steps: int = 64, store=None,
     ``steps`` is the short bracket; the long bracket is ``steps *
     mult`` and the steady per-step cost is their difference over the
     extra steps (falling back to the short bracket when differencing is
-    ill-conditioned, same as ``bench._batched_phase``)."""
+    ill-conditioned)."""
     import jax
     import jax.numpy as jnp
 

@@ -95,8 +95,7 @@ def test_cart_bitfused_step_compiles_on_2x2(mesh_2x2, monkeypatch):
     # LifeSim.__init__ would place a board on the (undescribable) mesh;
     # only the fields the builder reads are set.
     sim = object.__new__(LifeSim)
-    sim.layout, sim.mesh, sim.dtype, sim._plan = (
-        "cart", mesh_2x2, jnp.uint8, plan)
+    sim.layout, sim.mesh, sim._plan = "cart", mesh_2x2, plan
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     advance = sim._build_bitfused_advance()
     board = jax.ShapeDtypeStruct(
@@ -134,7 +133,7 @@ def test_collect_pack_compiles_without_collectives(request, where):
     mesh = place if where == "mesh_2x2" else None
     sharding = NamedSharding(mesh, P("y", "x")) if mesh else place
     sim = object.__new__(LifeSim)
-    sim.workload, sim.mesh = "life", mesh
+    sim.mesh = mesh
     sim.sharding = sharding if mesh else None
     board = jax.ShapeDtypeStruct((8192, 8192), jnp.uint8, sharding=sharding)
     sim.board = SimpleNamespace(shape=board.shape, sharding=sharding,

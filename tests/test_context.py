@@ -992,6 +992,51 @@ def test_ring_hop_flash_interpret_parity(rng, sp_mesh, pallas_interpret,
                                    rtol=1e-3, atol=1e-3)
 
 
+def test_ring_prefetch_matches_single_slot_schedule(rng, sp_mesh,
+                                                    pallas_interpret):
+    """The double-slot K/V hop prefetch (``:pf``) against the single-slot
+    schedule it deepens (MOMP_RING_PREFETCH=0), interpret mode on the
+    8-virtual-device mesh. Both run the same folds in the same order;
+    only the rotation issue points move, so the forward must be
+    bit-identical and the gradients equal, and the stamps must say which
+    schedule ran."""
+    context = pallas_interpret
+    h, n, d = 4, 8 * 128, 128  # 128-per-shard hops: interpret-eligible
+    q, k, v = _qkv(rng, h, n, d)
+    p = sp_mesh.shape["sp"]
+
+    def ring(q_, k_, v_):
+        return ring_attention(q_, k_, v_, mesh=sp_mesh, causal=True)
+
+    def loss(q_, k_, v_):
+        return jnp.sum(ring(q_, k_, v_) ** 2)
+
+    def leg():
+        stamps = (context.ring_hop_engine_for(q, k, v, p=p, causal=True),
+                  context.ring_hop_bwd_engine_for(q, k, v, p=p,
+                                                  causal=True))
+        grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return (stamps, np.asarray(ring(q, k, v)),
+                [np.asarray(g) for g in grads])
+
+    (pf, pf_bwd), pf_fwd, pf_grads = leg()
+    assert pf == pf_bwd == "pallas:b128:pf"
+    np.testing.assert_allclose(
+        pf_fwd, np.asarray(attention_reference(q, k, v, causal=True)),
+        rtol=1e-4, atol=1e-4)
+    try:
+        context._RING_PREFETCH = False
+        jax.clear_caches()
+        (nopf, nopf_bwd), nopf_fwd, nopf_grads = leg()
+    finally:
+        context._RING_PREFETCH = True
+        jax.clear_caches()
+    assert nopf == nopf_bwd == "pallas:b128"
+    np.testing.assert_array_equal(nopf_fwd, pf_fwd)
+    for name, a, b in zip("dq dk dv".split(), pf_grads, nopf_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6, err_msg=name)
+
+
 def test_pallas_flash_interpret_shard_map_single_device(rng,
                                                         pallas_interpret):
     """A 1-device sp mesh with the Pallas dispatch force-engaged

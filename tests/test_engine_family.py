@@ -4,8 +4,8 @@ depth as a tuned axis, and the sentinel/ledger provenance plumbing.
 
 Everything runs on the conftest 8-virtual-device CPU mesh; parity is
 always against the NumPy oracle at the GATE-owned per-family tolerance
-(``stencils.parity_tol_for``) — the same gates ``bench.py --radius-ab``
-and the plan-store install path use.
+(``stencils.parity_tol_for``) — the same gates the tuner and the
+plan-store install path use.
 """
 
 import numpy as np

@@ -413,46 +413,6 @@ def test_trace_report_halo_section():
     assert "halo A/B" in text and "efficiency=80.0%" in text
 
 
-# ----------------------------------------------------- bench --sharded-ab
-
-
-def test_bench_sharded_ab_phase(monkeypatch):
-    """The A/B phase end-to-end on the conftest mesh: overlap and forced
-    sequential legs both run, parity-gated, provenance-stamped. (The
-    speedup assertion lives in the CI smoke on a bigger board; here we
-    only require the measurement to be well-formed.)"""
-    from types import SimpleNamespace
-
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    args = SimpleNamespace(sharded_ab=16, sharded_board=64)
-    fields = bench._sharded_ab_phase(args, "life")
-    assert "sharded_ab_error" not in fields, fields
-    assert fields["sharded_halo"].startswith("overlap:")
-    assert fields["sharded_seq_halo"] == "seq:halo"
-    assert fields["sharded_ab_parity"] is True
-    assert fields["sharded_overlap_cups"] > 0
-    assert fields["sharded_seq_cups"] > 0
-    assert fields["vs_sequential"] > 0
-    assert 0.0 <= fields["sharded_overlap_efficiency"] <= 1.0
-    assert fields["sharded_exposed_s"] <= fields["sharded_transfer_s"]
-    # PR 18: the partitioned-boundary sweep rides the same phase — all
-    # three layouts parity-green under the split boundary, stamped and
-    # priced against the coupled schedule.
-    assert fields["sharded_boundary_parity"] is True
-    for lay in ("row", "col", "cart"):
-        assert fields["sharded_boundary_engines"][lay].endswith(":pb1")
-    assert fields["sharded_boundary_cups"] > 0
-    assert fields["sharded_boundary_vs_coupled"] > 0
-    # The kill switch downgrades the stamp on the SAME phase call — the
-    # provenance signal the sentinel alarms on.
-    monkeypatch.setenv(haloplan.ENV_OVERLAP, "0")
-    fields = bench._sharded_ab_phase(args, "life")
-    assert fields["sharded_halo"] == "seq:halo"
-
-
 # ------------------------------------------------ apps/life --resume + plans
 
 

@@ -223,10 +223,12 @@ def test_bench_refuses_an_unpinned_cpu(capsys, monkeypatch):
     assert "value" not in rec
 
 
-def test_bench_phase_error_exits_nonzero(capsys, monkeypatch):
-    """An opt-in phase that fails keeps its ``*_error`` field on the
-    printed line, and the run exits non-zero."""
+def test_bench_phase_error_exits_nonzero(capsys, monkeypatch, tmp_path):
+    """A headline extra that fails (here the trace probe) keeps its
+    ``*_error`` field on the printed line, and the run exits non-zero."""
     import json
+
+    from mpi_and_open_mp_tpu.parallel import context
 
     sys.path.insert(0, REPO)
     import bench
@@ -234,11 +236,13 @@ def test_bench_phase_error_exits_nonzero(capsys, monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("injected")
 
-    monkeypatch.setattr(bench, "_batched_phase", boom)
-    rc = bench.main(["--board", "64", "--steps", "64", "--batch", "2"])
+    trace = str(tmp_path / "trace.jsonl")
+    monkeypatch.setenv("MOMP_TRACE", trace)  # restored after the test
+    monkeypatch.setattr(context, "ring_attention", boom)
+    rc = bench.main(["--board", "64", "--steps", "64", "--trace", trace])
     assert rc == 1
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["batched_error"] == "RuntimeError: injected"
+    assert rec["trace_probe_error"] == "RuntimeError: injected"
     assert rec["value"] > 0
 
 

@@ -8,7 +8,7 @@ enforces a monotone rate ladder and the knee reads off the last rung
 that met the SLO; the hysteresis controller cannot flap — an action
 needs a full consecutive streak on one side and any action opens a
 cooldown window. Sentinel polarity for the three published fields rides
-along, as every bench phase's does.
+along.
 """
 
 import os

@@ -411,58 +411,3 @@ def test_sentinel_fails_pf_loss_not_pf_gain():
     verdict = regression_sentinel.evaluate(entries)
     assert not [d for d in verdict.get("downgrades", [])
                 if d["field"] == "ring_hop_engine"]
-
-
-# --------------------------------------------------------- bench --ring-ab
-
-
-def test_bench_ring_ab_phase(monkeypatch, tmp_path):
-    """The hop-prefetch A/B end-to-end on the conftest mesh (interpret
-    mode): oracle gate, pf-vs-single-slot bit parity both directions,
-    chained-differenced rates, rotation-priced exposed accounting, and
-    the kill-switch refusal that downgrades the stamps. Runs with a
-    live trace sink: with tracing on, ring_attention reroutes to the
-    hop-by-hop telemetry dispatch (host RTT per hop, no grad path) —
-    the phase must pin MOMP_TRACE_HOPS=0 so the A/B prices the
-    production fused schedule, and must restore the env after."""
-    from types import SimpleNamespace
-
-    from mpi_and_open_mp_tpu.parallel import context
-
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    jax.clear_caches()
-    monkeypatch.setattr(context, "_PALLAS_INTERPRET", True)
-    monkeypatch.setenv("MOMP_TRACE", str(tmp_path / "ring_trace.jsonl"))
-    monkeypatch.delenv("MOMP_TRACE_HOPS", raising=False)
-    args = SimpleNamespace(ring_ab=16)
-    try:
-        fields = bench._ring_ab_phase(args)
-    finally:
-        jax.clear_caches()
-    assert "ring_ab_error" not in fields, fields
-    assert "MOMP_TRACE_HOPS" not in os.environ
-    assert fields["ring_hop_engine"].startswith("pallas:")
-    assert fields["ring_hop_engine"].endswith(":pf")
-    assert fields["ring_hop_engine_bwd"].endswith(":pf")
-    assert fields["ring_nopf_engine"] == fields["ring_hop_engine"][:-3]
-    assert fields["ring_ab_parity"] is True
-    assert fields["ring_ab_grad_parity"] is True
-    assert fields["ring_prefetch_tflops"] > 0
-    assert fields["ring_vs_nopf"] > 0
-    assert 0.0 <= fields["ring_exposed_s"] <= fields["ring_transfer_s"]
-    assert fields["ring_exposed_nopf_s"] == fields["ring_transfer_s"]
-    assert 0.0 <= fields["ring_prefetch_efficiency"] <= 1.0
-
-    # Kill switch: the phase refuses to bless a non-prefetch run and the
-    # downgraded stamps ride the line for the sentinel.
-    monkeypatch.setattr(context, "_RING_PREFETCH", False)
-    jax.clear_caches()
-    try:
-        fields = bench._ring_ab_phase(args)
-    finally:
-        jax.clear_caches()
-    assert "not engaged" in fields["ring_ab_error"]
-    assert not fields["ring_hop_engine"].endswith(":pf")

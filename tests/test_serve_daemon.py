@@ -15,8 +15,6 @@ ticket ends in a result or an explicit shed, requests == resolved + shed.
 """
 
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -31,8 +29,6 @@ from mpi_and_open_mp_tpu.serve import (
 )
 from mpi_and_open_mp_tpu.serve import policy as policy_mod
 from mpi_and_open_mp_tpu.serve.queue import DONE, PENDING, SHED
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -395,7 +391,7 @@ def test_chaos_soak_every_ticket_terminal(monkeypatch, make_board):
             assert t.reason in SHED_REASONS
 
 
-# --------------------------------------------------------------- CLI + bench
+# --------------------------------------------------------------------- CLI
 
 
 def test_daemon_cli_preempt_exits_75_then_resume_verifies(
@@ -435,29 +431,3 @@ def test_daemon_cli_resume_requires_checkpoint(capsys):
     with pytest.raises(SystemExit) as ei:
         daemon_cli.main(["--resume"])
     assert ei.value.code == 2
-
-
-def test_bench_serve_phase_fields(monkeypatch, capsys):
-    """``bench.py --serve N``: the daemon phase's latency/shed/degrade
-    fields ride the ONE JSON line with the reserved ``serve_daemon_*`` /
-    percentile names and a passed parity gate."""
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    rc = bench.main(["--board", "32", "--steps", "16", "--serve", "6"])
-    assert rc == 0
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["serve_daemon_requests"] == 6
-    assert rec["serve_resolved"] + rec["serve_shed"] == 6
-    assert rec["serve_daemon_parity"] is True
-    assert rec["serve_p99_latency_s"] >= rec["serve_p50_latency_s"] >= 0
-    assert rec["serve_requests_per_sec"] > 0
-    assert rec["serve_shed_reasons"] == {}
-    # The WAL-on second burst prices the durability tax on the same line
-    # (baseline serve_* fields stay WAL-off for the sentinel's history).
-    assert rec["serve_wal_fsync"] == "every-record"
-    assert rec["serve_wal_records"] >= 6 and rec["serve_wal_bytes"] > 0
-    assert rec["serve_wal_syncs"] > 0 and rec["serve_wal_fsync_s"] >= 0
-    assert rec["serve_wal_parity"] is True
-    assert rec["serve_wal_p99_latency_s"] >= rec["serve_wal_p50_latency_s"]

@@ -4,7 +4,7 @@ the sentinel/ledger provenance plumbing.
 
 Everything runs on the conftest 8-virtual-device CPU mesh; parity is
 always against the NumPy oracle (``conftest.oracle_n``) or the dense
-sharded runner — the same gates ``bench.py --sparse-sharded-ab`` uses.
+sharded runner.
 """
 
 import numpy as np
@@ -125,7 +125,7 @@ def test_crossover_falls_back_dense(make_board):
 
 def test_bit_identity_vs_dense_sharded():
     """The reassembled sparse-sharded board equals the dense sharded
-    schedule bit-for-bit — the same gate the bench A/B enforces."""
+    schedule bit-for-bit."""
     board = _glider_board()
     mesh = mesh_lib.make_mesh_1d()
     eng = SparseShardedEngine(LIFE, board, mesh=mesh, layout="row",

@@ -14,7 +14,6 @@ without touching the store. All on the 8-virtual-device CPU mesh.
 
 import glob
 import os
-from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -291,39 +290,6 @@ def test_daemon_stencil_rung_order_follows_plan():
                        for _ in range(2)]).astype(gs.np_dtype)
     names = [n for n, _ in d._engines(gstack, 2, spec=gs)]
     assert names == ["batch:stencil:gray_scott", "oracle"]
-
-
-def test_bench_autotune_phase_fresh_then_store(tmp_path):
-    """The ``--autotune`` phase contract end to end: pass 1 tunes fresh
-    and persists; pass 2 (clean metrics — a restarted process's view)
-    installs from the store and reports an EMPTY life_batch retrace
-    delta; the kill switch skips with an explicit fallback_reason."""
-    import bench
-
-    args = SimpleNamespace(autotune=16, tune_board=16, tune_batch=8,
-                           plans=str(tmp_path))
-    out1 = bench._autotune_phase(args, "life")
-    assert out1["plan_source"] == "fresh"
-    assert out1["vs_heuristic"] >= 1.0
-    assert out1["tuned_cups"] > 0 and out1["heuristic_cups"] > 0
-    assert out1["plan_file"].endswith(out1["tune_digest"] + ".plan")
-
-    pallas_life.clear_planned_paths()
-    metrics.reset()
-    out2 = bench._autotune_phase(args, "life")
-    assert out2["plan_source"] == "store"
-    assert out2["tuned_path"] == out1["tuned_path"]
-    assert out2["vs_heuristic"] == out1["vs_heuristic"]
-    assert out2["tune_retraces"] == {}
-    assert out2["plans"]["installed"] == 1
-
-    os.environ["MOMP_TUNE"] = "0"
-    try:
-        out3 = bench._autotune_phase(args, "life")
-    finally:
-        del os.environ["MOMP_TUNE"]
-    assert out3["plan_source"] == "heuristic"
-    assert "MOMP_TUNE=0" in out3["fallback_reason"]
 
 
 # -- ledger + sentinel -----------------------------------------------------
