@@ -57,7 +57,9 @@ over the same engines.
 
 The run loop preserves the reference's ordering (``3-life/life_mpi.c:51-62``):
 at step ``i``, save a snapshot when ``i % save_steps == 0`` (i.e. *before*
-stepping), then advance one step. Collect-to-host is ``jax.device_get`` of
+stepping), then advance one step; where nothing else stops the loop, one
+device program steps a chunk of save intervals and one fetch brings its
+frames to the host (``LifeSim.run``). Collect-to-host is ``jax.device_get`` of
 the sharded array — the ``MPI_Gather``/manual-recv-loop equivalent
 (``5-gather/life_mpi.c:178``, ``3-life/life_mpi.c:185-196``); a Life board
 of 32 MiB or more crosses as bit-packed words (``LifeSim.collect``).
@@ -160,6 +162,13 @@ def _note_retrace(fn: str) -> None:
 # 100 ms), where each fresh board is mapped and faulted in anew (glibc
 # serves arrays above 32 MiB from fresh mappings).
 _PACK_MIN_BYTES = 32 << 20
+
+# Bytes of frames that one chunk program of ``run()``'s snapshot path
+# stacks on the device and fetches in one transfer: 279 frames of a
+# 300x100 board, a quarter of the size at which the host maps fresh pages
+# for the fetch. A board of more than half of this keeps one dispatch and
+# one fetch a frame.
+_FRAME_CHUNK_BYTES = 8 << 20
 
 # Output bytes per task of ``_unpack_words``.
 _UNPACK_CHUNK_BYTES = 1 << 20
@@ -373,6 +382,7 @@ class LifeSim:
         self._run_id = 0
         self.reset()
         self._advance = self._build_advance()
+        self._frames = self._build_frames()
         self._pack = self._build_pack()
 
     # ---------------------------------------------------------- step builders
@@ -655,6 +665,35 @@ class LifeSim:
 
         return advance
 
+    def _build_frames(self) -> Callable:
+        """Return ``frames(board, count, last)``, the program of one chunk
+        of ``run()``'s snapshot path, jit-cached on ``count`` and ``last``.
+
+        It takes the board at a save point and gives ``count`` frames:
+        each the board cropped to ``(ny, nx)``, then ``save_steps`` steps
+        of ``advance``, except that the last frame's interval is ``last``
+        steps. It returns the board after them and the ``(count, ny,
+        nx)`` frames, so that the host makes one dispatch and one fetch a
+        chunk instead of one of each a frame.
+        """
+        ny, nx = self.cfg.shape
+        every = self.cfg.save_steps
+
+        @functools.partial(jax.jit, static_argnums=(1, 2))
+        def frames(board, count, last):
+            _note_retrace("life_frames")
+
+            def interval(b, _):
+                return self._advance(b, every), b[:ny, :nx]
+
+            with jax.named_scope("life_frames"):
+                board, head = lax.scan(interval, board, length=count - 1)
+                tail = board[None, :ny, :nx]
+                board = self._advance(board, last)
+            return board, jnp.concatenate([head, tail])
+
+        return frames
+
     def _build_pack(self) -> Callable[[jnp.ndarray], jnp.ndarray] | None:
         """The program ``collect()`` packs the board with, or None where
         it fetches bytes.
@@ -790,6 +829,38 @@ class LifeSim:
             lengths.add(next_stop - i)
             i = next_stop
         return sorted(lengths)
+
+    def _frame_chunks(self, save: bool) -> tuple[int, list] | None:
+        """The chunked snapshot path of ``run()`` from ``step_count``, or
+        None where ``run()`` stops at every saved step.
+
+        It is taken where the save cadence is the loop's only stop: VTK
+        frames and no checkpoints, no fault plan, guards off, one board,
+        one process, and at least two frames to a chunk of
+        ``_FRAME_CHUNK_BYTES``. It is ``(lead, chunks)``: the steps up to
+        the first save point, then ``(start, count, last)`` for each
+        chunk, ``last`` being the steps after its last frame.
+        """
+        from mpi_and_open_mp_tpu.robust import chaos, guards
+
+        cfg = self.cfg
+        every = cfg.save_steps
+        size = _FRAME_CHUNK_BYTES // (cfg.ny * cfg.nx)
+        if not (save and every > 0 and size > 1
+                and self.outdir is not None and self.checkpoint_dir is None
+                and self.batch is None and chaos.active_plan() is None
+                and not guards.guards_active()
+                and jax.process_count() == 1):
+            return None
+        i = min(-(-self.step_count // every) * every, cfg.steps)
+        lead = max(0, i - self.step_count)
+        chunks = []
+        while i < cfg.steps:
+            count = min(size, -(-(cfg.steps - i) // every))
+            stop = min(i + count * every, cfg.steps)
+            chunks.append((i, count, stop - i - (count - 1) * every))
+            i = stop
+        return lead, chunks
 
     def _consistency_violation(self) -> str | None:
         """The semantic halo-consistency probe, as a description or None.
@@ -953,10 +1024,23 @@ class LifeSim:
         goes through ``anchor_sync`` (not a whole-array fetch): on
         multi-host runs the board spans non-addressable devices, where a
         full ``device_get`` is impossible.
+
+        Where ``run()`` takes its chunked snapshot path
+        (``_frame_chunks``), that is the advance up to the first save
+        point and the chunk program of each chunk length it will use: the
+        full chunk and the last one.
         """
         from mpi_and_open_mp_tpu.utils.timing import anchor_sync
 
-        for n in self._segment_lengths():
+        chunked = self._frame_chunks(save=True)
+        if chunked is None:
+            lengths = self._segment_lengths()
+        else:
+            lead, chunks = chunked
+            lengths = [lead] if lead else []
+            for shape in sorted({c[1:] for c in chunks}):
+                anchor_sync(self._frames(self.board, *shape), fetch_all=True)
+        for n in lengths:
             anchor_sync(self._advance(self.board, n), fetch_all=True)
         if self._pack is not None:
             anchor_sync(self._pack(self.board), fetch_all=True)
@@ -1008,16 +1092,21 @@ class LifeSim:
             # applies to the trailing board axes either way.
             return full[..., : self.cfg.ny, : self.cfg.nx]
 
-    def save_snapshot(self) -> str:
+    def save_snapshot(self, frame: np.ndarray | None = None,
+                      step: int | None = None) -> str:
+        """Write the VTK frame of the live board at ``step_count``, or of
+        ``frame``, a host board of step ``step`` (a frame that ``run()``'s
+        chunked path has already fetched). Returns its path."""
         assert self.outdir is not None, "LifeSim(outdir=...) required to save"
-        path = vtk_lib.vtk_path(self.outdir, self.step_count)
+        if frame is None:
+            step = self.step_count
+        path = vtk_lib.vtk_path(self.outdir, step)
         # collect() is COLLECTIVE on multi-host runs (cross-process
         # allgather) — every process must enter it; only process 0 writes
         # the file, the reference's write-from-one-rank discipline
         # (3-life/life_mpi.c:54-57; shared-FS double-writes otherwise).
-        with trace.span("life.snapshot", run=self._run_id,
-                        step=self.step_count):
-            board = self.collect()
+        with trace.span("life.snapshot", run=self._run_id, step=step):
+            board = self.collect() if frame is None else frame
             if jax.process_index() == 0:
                 with trace.span("life.vtk_write", run=self._run_id) as sp:
                     os.makedirs(self.outdir, exist_ok=True)
@@ -1035,12 +1124,54 @@ class LifeSim:
                 os.path.join(self.checkpoint_dir, f"step_{self.step_count:06d}")
             )
 
+    def _segment_span(self, start: int, stop: int, guarded: bool = False):
+        """The span of one advance of ``run()`` from ``start`` to
+        ``stop``."""
+        return trace.span("life.segment", run=self._run_id, start=start,
+                          stop=stop, impl=self.impl, layout=self.layout,
+                          guarded=guarded)
+
+    def _run_chunked(self, lead: int, chunks: list) -> None:
+        """``run()``'s chunked snapshot path (``_frame_chunks``): one
+        dispatch of the chunk program and one fetch of its frames a chunk,
+        then each frame's write in step order. The span ``life.frames``
+        (``start``, ``frames``, ``wire_bytes``) holds a chunk's dispatch,
+        as ``life.segment``, and its fetch."""
+        every = self.cfg.save_steps
+        if lead:
+            with self._segment_span(self.step_count,
+                                    self.step_count + lead) as sp:
+                self.step(lead)
+                sp.anchor(self.board)
+        for start, count, last in chunks:
+            stop = start + (count - 1) * every + last
+            with trace.span("life.frames", run=self._run_id, start=start,
+                            frames=count) as fsp:
+                with self._segment_span(start, stop) as sp:
+                    self.board, frames = self._frames(self.board, count, last)
+                    sp.anchor(self.board)
+                frames = jax.device_get(frames)
+                fsp.set(wire_bytes=frames.nbytes)
+            self.step_count = stop
+            for k, frame in enumerate(frames):
+                self.save_snapshot(frame, start + k * every)
+
     def run(self, save: bool | None = None) -> np.ndarray:
         """Run ``cfg.steps`` steps with the reference's save cadence.
 
         Snapshots are written at every step index ``i < steps`` with
         ``i % save_steps == 0`` (before stepping), matching
         ``3-life/life_mpi.c:51-58``. Returns the final board.
+
+        Where the save cadence is the loop's only stop (VTK frames, no
+        checkpoints, no fault plan, guards off, one board, one process)
+        and two frames or more fit in ``_FRAME_CHUNK_BYTES``, the frames
+        come in chunks (``_frame_chunks``): one device program steps a
+        chunk of save intervals and stacks the board of each save point,
+        one fetch brings them to the host, and they are written in step
+        order. The frames, their steps, their bytes and the final board
+        are those of the loop below, which stops at every saved step and
+        serves every other case.
 
         Robustness (all inert on the default path): periodic Orbax
         checkpoints every ``checkpoint_every`` steps; SIGTERM/SIGINT flush
@@ -1079,6 +1210,10 @@ class LifeSim:
                     self.step(cfg.steps - self.step_count)
                     sp.anchor(self.board)
             return self.collect()
+        chunked = self._frame_chunks(save)
+        if chunked is not None:
+            self._run_chunked(*chunked)
+            return self.collect()
         i = self.step_count
         with preempt.flush_on_signal(
                 enabled=self.checkpoint_dir is not None) as sig:
@@ -1099,15 +1234,7 @@ class LifeSim:
                     time.sleep(plan.delay_s)
                 # Advance to the next boundary in one jit call.
                 next_stop = self._next_stop(i, save)
-                with trace.span(
-                    "life.segment",
-                    run=self._run_id,
-                    start=i,
-                    stop=next_stop,
-                    impl=self.impl,
-                    layout=self.layout,
-                    guarded=guard,
-                ) as sp:
+                with self._segment_span(i, next_stop, guard) as sp:
                     if guard:
                         self._guarded_step(next_stop - i)
                     else:

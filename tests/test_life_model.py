@@ -301,8 +301,17 @@ def test_run_spans_share_the_run_number(make_board, sink):
         assert r["dur"] > 0 and r["parent"] is None
 
 
+@pytest.mark.parametrize("chunked", [True, False])
 def test_snapshot_span_holds_collect_and_vtk_write(make_board, sink,
-                                                   tmp_path):
+                                                   tmp_path, monkeypatch,
+                                                   chunked):
+    """A frame fetched one at a time is collected inside its snapshot
+    span; a chunk's frames are fetched together in ``life.frames``, so
+    their snapshot spans hold the write alone."""
+    from mpi_and_open_mp_tpu.models import life
+
+    if not chunked:
+        monkeypatch.setattr(life, "_FRAME_CHUNK_BYTES", 0)
     board = make_board(16, 16)
     cfg = config_from_board(board, steps=4, save_steps=2)
     sim = LifeSim(cfg, layout="serial", impl="roll",
@@ -311,11 +320,13 @@ def test_snapshot_span_holds_collect_and_vtk_write(make_board, sink,
     recs = _spans(sink)
     snaps = [r for r in recs if r["name"] == "life.snapshot"]
     assert [s["attrs"]["step"] for s in snaps] == [0, 2]
+    assert len([r for r in recs if r["name"] == "life.frames"]) == chunked
     for snap in snaps:
         kids = [r for r in recs if r["parent"] == snap["id"]]
-        assert [k["name"] for k in kids] == ["life.collect",
-                                             "life.vtk_write"]
-        (write,) = kids[1:]
+        assert [k["name"] for k in kids] == (
+            ["life.vtk_write"] if chunked
+            else ["life.collect", "life.vtk_write"])
+        write = kids[-1]
         assert write["attrs"]["bytes"] == os.path.getsize(
             tmp_path / "vtk" / f"life_{snap['attrs']['step']:06d}.vtk")
         assert {k["attrs"]["run"] for k in kids} == {snap["attrs"]["run"]}
