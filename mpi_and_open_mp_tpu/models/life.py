@@ -204,6 +204,20 @@ def _unpack_words(words: np.ndarray) -> np.ndarray:
     return out.reshape(*words.shape[:-1], out.shape[-1])
 
 
+def _round_halo_bytes(plan) -> int:
+    """Bytes one shard sends over ``ppermute`` in one exchange round of
+    the sharded bitfused advance: a ghost each way on every sharded axis,
+    x first on the ``(nw_s, W)`` packed shard, then y on the x-extended
+    one, each as ``halo.packed_halo_x``/``packed_halo_y`` slice it."""
+    cols, words = plan.W, 0
+    if plan.x_sharded:
+        words += 2 * plan.nw_s * halo.packed_send_x(plan.hx, plan.pad_x)
+        cols += 2 * plan.hx
+    if plan.y_sharded:
+        words += 2 * cols * halo.packed_send_y(plan.h, plan.pad_y)
+    return 4 * words
+
+
 class LifeSim:
     """One Life run: sharded board state + compiled steppers + snapshot IO."""
 
@@ -381,6 +395,9 @@ class LifeSim:
         # every span of a run carries it as ``run``.
         self._run_id = 0
         self.reset()
+        # What a sharded bitfused advance exchanges (its stepping spans'
+        # counters, ``_exchange_attrs``); only _build_bitfused_advance sets it.
+        self._exchange = None
         self._advance = self._build_advance()
         self._frames = self._build_frames()
         self._pack = self._build_pack()
@@ -599,6 +616,8 @@ class LifeSim:
             if eligible else None
         )
         use_overlap = hp is not None and hp.overlap
+        if mesh.size > 1:
+            self._exchange = (plan, _round_halo_bytes(plan))
         # 1-shard / ineligible geometry keeps the bare mode string (the
         # historical note); capable geometry appends the schedule stamp.
         self.plan_note = (
@@ -1124,12 +1143,30 @@ class LifeSim:
                 os.path.join(self.checkpoint_dir, f"step_{self.step_count:06d}")
             )
 
-    def _segment_span(self, start: int, stop: int, guarded: bool = False):
-        """The span of one advance of ``run()`` from ``start`` to
-        ``stop``."""
+    def _exchange_attrs(self, *advances: int) -> dict:
+        """The counters of a stepping span of a sharded bitfused advance,
+        for ``advance`` calls of these step counts: ``board_cells``
+        (``ny * nx``), ``frame_cells`` (the padded frame the kernel
+        steps), ``rounds`` (exchange rounds, ``k_max`` steps or fewer
+        each) and ``halo_bytes`` (what one chip sends over ``ppermute``
+        in them). Empty on every other path."""
+        if self._exchange is None:
+            return {}
+        plan, round_bytes = self._exchange
+        rounds = sum(-(-n // plan.k_max) for n in advances)
+        return {"board_cells": plan.shape[0] * plan.shape[1],
+                "frame_cells": plan.frame[0] * plan.frame[1],
+                "rounds": rounds, "halo_bytes": rounds * round_bytes}
+
+    def _segment_span(self, start: int, stop: int, guarded: bool = False,
+                      advances: tuple[int, ...] = ()):
+        """The span of ``run()``'s stepping from ``start`` to ``stop``:
+        one advance of ``stop - start`` steps, or the ``advances`` of a
+        snapshot chunk."""
         return trace.span("life.segment", run=self._run_id, start=start,
                           stop=stop, impl=self.impl, layout=self.layout,
-                          guarded=guarded)
+                          guarded=guarded,
+                          **self._exchange_attrs(*advances or (stop - start,)))
 
     def _run_chunked(self, lead: int, chunks: list) -> None:
         """``run()``'s chunked snapshot path (``_frame_chunks``): one
@@ -1147,7 +1184,9 @@ class LifeSim:
             stop = start + (count - 1) * every + last
             with trace.span("life.frames", run=self._run_id, start=start,
                             frames=count) as fsp:
-                with self._segment_span(start, stop) as sp:
+                with self._segment_span(
+                        start, stop,
+                        advances=(every,) * (count - 1) + (last,)) as sp:
                     self.board, frames = self._frames(self.board, count, last)
                     sp.anchor(self.board)
                 frames = jax.device_get(frames)
@@ -1200,14 +1239,16 @@ class LifeSim:
             # shared no-op singleton when MOMP_TRACE is unset) anchors on
             # the board so its duration covers execution, not dispatch.
             if cfg.steps > self.step_count:
+                steps = cfg.steps - self.step_count
                 with trace.span(
                     "life.advance",
                     run=self._run_id,
-                    steps=cfg.steps - self.step_count,
+                    steps=steps,
                     impl=self.impl,
                     layout=self.layout,
+                    **self._exchange_attrs(steps),
                 ) as sp:
-                    self.step(cfg.steps - self.step_count)
+                    self.step(steps)
                     sp.anchor(self.board)
             return self.collect()
         chunked = self._frame_chunks(save)

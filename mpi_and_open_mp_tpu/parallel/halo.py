@@ -104,6 +104,19 @@ def halo_pad_x(block: jnp.ndarray, axis_name: str = "x", depth: int = 1) -> jnp.
     return jnp.concatenate([left, block, right], axis=-1)
 
 
+def packed_send_y(h: int, pad: int) -> int:
+    """Word rows :func:`packed_halo_y` sends each way: ``h``, or with
+    ``pad`` mirror rows the ``h + 1 + pad // 32`` that hold the wrap
+    shard's funnel sources and the mirror refresh."""
+    return h + 1 + pad // 32 if pad else h
+
+
+def packed_send_x(hx: int, pad: int) -> int:
+    """Columns :func:`packed_halo_x` sends each way: ``hx``, or with
+    ``pad`` mirror columns ``hx + pad``."""
+    return hx + pad if pad else hx
+
+
 def packed_halo_y(
     e: jnp.ndarray, axis_name: str = "y", h: int = 4, *, pad: int = 0
 ) -> jnp.ndarray:
@@ -123,7 +136,7 @@ def packed_halo_y(
         return halo_pad_y(e, axis_name, h)
     _note_exchange("packed_y", axis_name)
     p = lax.axis_size(axis_name)
-    s = h + 1 + pad // 32
+    s = packed_send_y(h, pad)
     # Chaos wraps the INCOMING top ghost only (injection-point parity
     # with halo_pad_y): `dn` also refreshes the wrap shard's mirror
     # rows from live data, a write chaos must never corrupt.
@@ -161,7 +174,7 @@ def packed_halo_x(
         return halo_pad_x(block, axis_name, hx)
     _note_exchange("packed_x", axis_name)
     p = lax.axis_size(axis_name)
-    s = hx + pad
+    s = packed_send_x(hx, pad)
     # Chaos on the incoming left ghost only — `right` also feeds the
     # wrap shard's mirror-column refresh (see packed_halo_y).
     left = _chaos_ghost(

@@ -83,15 +83,21 @@ def test_single_chip_kernel_compiles(one_chip, fn, shape):
     assert "tpu_custom_call" in _kernel_text(fn, shape, one_chip)
 
 
-def test_cart_bitfused_step_compiles_on_2x2(mesh_2x2, monkeypatch):
+@pytest.mark.parametrize("shape", [(8192, 8192), (10000, 10000)],
+                         ids=["8192", "10000"])
+def test_cart_bitfused_step_compiles_on_2x2(mesh_2x2, monkeypatch, shape):
     """The cart layout's bitfused halo program as ``LifeSim`` builds it
     (``_build_bitfused_advance``), steered onto its TPU branch: the
-    stepper's kernel and the halo's collective-permute are both there."""
+    stepper's kernel and the halo's collective-permute are both there.
+    8192² is an exact frame; 10000² lives in a 10240² frame whose 240
+    mirror rows and columns the padded exchange (funnel-shifted wrap
+    ghosts, mirror refresh) keeps."""
     from mpi_and_open_mp_tpu.models.life import LifeSim
 
-    plan = bitlife.plan_sharded_bits((8192, 8192), 2, 2,
+    plan = bitlife.plan_sharded_bits(shape, 2, 2,
                                      y_sharded=True, x_sharded=True)
-    assert plan is not None
+    assert plan is not None and plan.mode == "tiled"
+    assert (plan.pad_y > 0) == (plan.pad_x > 0) == (shape[0] % 128 > 0)
     # LifeSim.__init__ would place a board on the (undescribable) mesh;
     # only the fields the builder reads are set.
     sim = object.__new__(LifeSim)

@@ -1,0 +1,121 @@
+"""The counters on a sharded bitfused advance's stepping spans
+(``LifeSim._exchange_attrs``): cells of the board and of the padded frame
+the kernel steps, exchange rounds, and the bytes a chip sends, tied to
+the ``collective-permute``s of the lowered program."""
+
+import json
+import re
+from functools import partial
+
+import numpy as np
+import pytest
+
+from mpi_and_open_mp_tpu.models.life import LifeSim
+from mpi_and_open_mp_tpu.obs import trace
+from mpi_and_open_mp_tpu.ops import bitlife
+from mpi_and_open_mp_tpu.parallel import mesh as mesh_lib
+from mpi_and_open_mp_tpu.utils.config import config_from_board
+
+from conftest import oracle_n
+
+KEYS = {"board_cells", "frame_cells", "rounds", "halo_bytes"}
+STEP_SPANS = ("life.advance", "life.segment")
+PERMUTE = re.compile(r"stablehlo\.collective_permute\".*?:\s*"
+                     r"\(tensor<((?:\d+x)*)(u?i|f)(\d+)>\)")
+
+
+def permute_bytes(sim) -> int:
+    """Summed operand bytes of the ``collective_permute``s in the
+    lowered advance: one exchange round's, since the round is the body
+    of the advance's loop."""
+    text = sim._advance.lower(sim.board, 1).as_text()
+    total = 0
+    for line in text.splitlines():
+        m = PERMUTE.search(line)
+        if m:
+            dims = [int(d) for d in m.group(1).split("x") if d]
+            total += int(np.prod(dims)) * int(m.group(3)) // 8
+    return total
+
+
+def spans_of(path):
+    with open(path) as fd:
+        return [r for r in map(json.loads, fd)
+                if r["kind"] == "span" and r["name"] in STEP_SPANS]
+
+
+@pytest.fixture
+def traced(tmp_path, monkeypatch):
+    path = tmp_path / "spans.jsonl"
+    monkeypatch.setenv("MOMP_TRACE", str(path))
+    trace.reset()
+    yield path
+    trace.reset()
+
+
+# (shape, VMEM budget): an exact 512² frame (window stepper), and 784x528
+# in a 1024x768 frame, padded on both sharded axes, tiled as 10000² is.
+GEOMETRIES = {"aligned": ((512, 512), bitlife._PACKED_VMEM_LIMIT),
+              "unaligned": ((784, 528), 30_000)}
+
+
+def cart_sim(make_board, monkeypatch, geometry, steps, save_steps,
+             outdir=None):
+    shape, budget = GEOMETRIES[geometry]
+    monkeypatch.setattr(bitlife, "plan_sharded_bits",
+                        partial(bitlife.plan_sharded_bits, budget=budget))
+    board = make_board(*shape)
+    sim = LifeSim(config_from_board(board, steps, save_steps), layout="cart",
+                  impl="bitfused", mesh=mesh_lib.make_mesh_2d(2, 2),
+                  outdir=outdir)
+    return sim, board
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_advance_span_counts_what_the_program_sends(
+        make_board, monkeypatch, traced, geometry):
+    sim, board = cart_sim(make_board, monkeypatch, geometry,
+                          steps=140, save_steps=0)
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 140))
+    (span,) = spans_of(traced)
+    attrs = span["attrs"]
+    assert span["name"] == "life.advance" and KEYS <= set(attrs)
+    ny, nx = board.shape
+    assert attrs["board_cells"] == ny * nx
+    fy, fx = sim._plan.frame
+    assert attrs["frame_cells"] == fy * fx
+    if geometry == "aligned":
+        assert attrs["frame_cells"] == attrs["board_cells"]
+    else:
+        assert attrs["frame_cells"] > attrs["board_cells"]
+    assert attrs["rounds"] == 2  # 128 + 12 steps
+    assert attrs["halo_bytes"] == attrs["rounds"] * permute_bytes(sim)
+
+
+def test_snapshot_chunk_counts_each_advance(make_board, monkeypatch, traced,
+                                            tmp_path):
+    """A chunk of the snapshot path steps its save intervals as separate
+    advances, so 140 steps in intervals of 50 take three rounds, not
+    the two that one advance of 140 would."""
+    sim, board = cart_sim(make_board, monkeypatch, "unaligned", steps=140,
+                          save_steps=50, outdir=tmp_path / "vtk")
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 140))
+    (span,) = spans_of(traced)
+    assert span["name"] == "life.segment"
+    assert span["attrs"]["rounds"] == 3
+    assert span["attrs"]["halo_bytes"] == 3 * permute_bytes(sim)
+
+
+@pytest.mark.parametrize("layout,impl,mesh", [
+    ("serial", "roll", None),
+    ("row", "bitfused", (1, 1)),
+    ("cart", "halo", (2, 2)),
+], ids=["serial", "bitfused_one_device", "cart_halo"])
+def test_other_paths_carry_no_counters(make_board, traced, layout, impl, mesh):
+    board = make_board(64, 64)
+    sim = LifeSim(config_from_board(board, steps=20, save_steps=0),
+                  layout=layout, impl=impl,
+                  mesh=mesh_lib.make_mesh_2d(*mesh) if mesh else None)
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 20))
+    (span,) = spans_of(traced)
+    assert not KEYS & set(span["attrs"])

@@ -145,6 +145,33 @@ def test_parity_bitfused_unaligned(make_board, shape, layout, mesh_args, steps):
     np.testing.assert_array_equal(sim.collect(), oracle_n(board, steps))
 
 
+@pytest.mark.parametrize("steps", [140, 300])  # two and three rounds
+def test_parity_bitfused_tiled_padded_cart(make_board, monkeypatch, steps):
+    """The tiled kernel on a 2x2 cart mesh with both sharded axes padded:
+    the 10000x10000 plan's features at a CPU size. 784x528 pitches as
+    10000² does (no 8-word split of 13 words, so 16 words a shard): a
+    1024x768 frame with 240 mirror rows and 240 mirror columns, more
+    than the 128-column halo, so the y ghosts are funnel-shifted across
+    words and the x mirrors reach past the ghosts. A small VMEM budget
+    rejects the window stepper and tiles each shard 2x3."""
+    from functools import partial
+
+    from mpi_and_open_mp_tpu.ops import bitlife
+
+    monkeypatch.setattr(bitlife, "plan_sharded_bits",
+                        partial(bitlife.plan_sharded_bits, budget=30_000))
+    board = make_board(784, 528, density=0.35)
+    cfg = config_from_board(board, steps=steps, save_steps=1000)
+    sim = LifeSim(cfg, layout="cart", impl="bitfused",
+                  mesh=mesh_lib.make_mesh_2d(2, 2))
+    plan = sim._plan
+    assert plan.mode == "tiled" and plan.frame == (1024, 768)
+    assert (plan.pad_y, plan.pad_x, plan.nw_s, plan.hx) == (240, 240, 16, 128)
+    assert steps > plan.k_max, "steps must cross a fused round"
+    sim.step(steps)
+    np.testing.assert_array_equal(sim.collect(), oracle_n(board, steps))
+
+
 def test_bitfused_1dev_serial_dispatch(make_board, monkeypatch):
     """A 1-device mesh has no neighbours: the bitfused path dispatches
     to the serial whole-board stepper (no ghost-window redundancy, no
