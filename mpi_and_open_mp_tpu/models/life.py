@@ -395,9 +395,11 @@ class LifeSim:
         # every span of a run carries it as ``run``.
         self._run_id = 0
         self.reset()
-        # What a sharded bitfused advance exchanges (its stepping spans'
-        # counters, ``_exchange_attrs``); only _build_bitfused_advance sets it.
+        # What a sharded bitfused advance exchanges, and the cells an
+        # advance through the fused tiled kernel steps: its stepping
+        # spans' counters (``_step_attrs``), set by the step builders.
         self._exchange = None
+        self._tile_cells = {}
         self._advance = self._build_advance()
         self._frames = self._build_frames()
         self._pack = self._build_pack()
@@ -442,6 +444,11 @@ class LifeSim:
             self.mesh is None or self.mesh.size == 1
         ):
             from mpi_and_open_mp_tpu.ops import pallas_life
+
+            shape = self.padded_shape
+            self._tile_cells = pallas_life.native_tile_cells(
+                pallas_life.native_path(
+                    shape, on_tpu=not pallas_life._interpret()), shape)
 
             def advance(board, n):
                 return pallas_life.life_run_vmem(board, n)
@@ -522,10 +529,13 @@ class LifeSim:
         if self.impl == "pallas":
             from mpi_and_open_mp_tpu.ops import pallas_life
 
-            self.plan_note = "batch:" + pallas_life.native_path_batch(
+            path = pallas_life.native_path_batch(
                 (self.batch, *self.cfg.shape),
                 on_tpu=jax.default_backend() == "tpu",
             )
+            self.plan_note = "batch:" + path
+            self._tile_cells = pallas_life.native_tile_cells(
+                path, self.cfg.shape, boards=self.batch)
 
             def advance(board, n):
                 return pallas_life.life_run_vmem_batch(board, n)
@@ -580,14 +590,15 @@ class LifeSim:
             # TPU-only: on CPU the interpret-mode tests keep exercising
             # the exchange machinery this fast path would bypass.
             from mpi_and_open_mp_tpu.ops.pallas_life import (
-                life_run_vmem, native_path)
+                life_run_vmem, native_path, native_tile_cells)
 
             ny, nx = self.cfg.shape
             fy, fx = plan.frame
             # on_tpu must mirror life_run_vmem's own dispatch decision
             # or this provenance label could name a path that never runs.
-            self.plan_note = ("serial-1dev:"
-                              f"{native_path((ny, nx), on_tpu=not interpret)}")
+            path = native_path((ny, nx), on_tpu=not interpret)
+            self.plan_note = f"serial-1dev:{path}"
+            self._tile_cells = native_tile_cells(path, (ny, nx))
 
             @jax.jit
             def advance(board, n):
@@ -618,6 +629,7 @@ class LifeSim:
         use_overlap = hp is not None and hp.overlap
         if mesh.size > 1:
             self._exchange = (plan, _round_halo_bytes(plan))
+        self._tile_cells = bitlife.plan_tile_cells(plan)
         # 1-shard / ineligible geometry keeps the bare mode string (the
         # historical note); capable geometry appends the schedule stamp.
         self.plan_note = (
@@ -1143,20 +1155,25 @@ class LifeSim:
                 os.path.join(self.checkpoint_dir, f"step_{self.step_count:06d}")
             )
 
-    def _exchange_attrs(self, *advances: int) -> dict:
-        """The counters of a stepping span of a sharded bitfused advance,
-        for ``advance`` calls of these step counts: ``board_cells``
-        (``ny * nx``), ``frame_cells`` (the padded frame the kernel
+    def _step_attrs(self, *advances: int) -> dict:
+        """The counters of a stepping span, for ``advance`` calls of these
+        step counts. An advance through the fused tiled kernel
+        (``life_fused_tiles``) carries ``window_cells`` (the cells one
+        fused step computes over all chips and grid programs, halo rows
+        and columns included), ``frame_cells`` (the frame it writes) and
+        ``board_cells`` (``ny * nx``). A sharded bitfused advance carries
+        ``board_cells``, ``frame_cells`` (the padded frame the kernel
         steps), ``rounds`` (exchange rounds, ``k_max`` steps or fewer
         each) and ``halo_bytes`` (what one chip sends over ``ppermute``
         in them). Empty on every other path."""
-        if self._exchange is None:
-            return {}
-        plan, round_bytes = self._exchange
-        rounds = sum(-(-n // plan.k_max) for n in advances)
-        return {"board_cells": plan.shape[0] * plan.shape[1],
-                "frame_cells": plan.frame[0] * plan.frame[1],
-                "rounds": rounds, "halo_bytes": rounds * round_bytes}
+        attrs = dict(self._tile_cells)
+        if self._exchange is not None:
+            plan, round_bytes = self._exchange
+            rounds = sum(-(-n // plan.k_max) for n in advances)
+            attrs.update(board_cells=plan.shape[0] * plan.shape[1],
+                         frame_cells=plan.frame[0] * plan.frame[1],
+                         rounds=rounds, halo_bytes=rounds * round_bytes)
+        return attrs
 
     def _segment_span(self, start: int, stop: int, guarded: bool = False,
                       advances: tuple[int, ...] = ()):
@@ -1166,7 +1183,7 @@ class LifeSim:
         return trace.span("life.segment", run=self._run_id, start=start,
                           stop=stop, impl=self.impl, layout=self.layout,
                           guarded=guarded,
-                          **self._exchange_attrs(*advances or (stop - start,)))
+                          **self._step_attrs(*advances or (stop - start,)))
 
     def _run_chunked(self, lead: int, chunks: list) -> None:
         """``run()``'s chunked snapshot path (``_frame_chunks``): one
@@ -1246,7 +1263,7 @@ class LifeSim:
                     steps=steps,
                     impl=self.impl,
                     layout=self.layout,
-                    **self._exchange_attrs(steps),
+                    **self._step_attrs(steps),
                 ) as sp:
                     self.step(steps)
                     sp.anchor(self.board)

@@ -28,8 +28,10 @@ is the packed bytes times the ~11 live step temporaries against the
 ~16 MB/core scoped-VMEM budget (see ``_PACKED_VMEM_LIMIT``): ~3200² is
 the measured ceiling. Beyond it, aligned boards run the multi-step-fused
 tiled kernel (:func:`life_run_fused_bits` — one HBM pass per up-to-128
-steps, measured 1.9 Tcups at 8192² on v5e) and anything else
-the compiled-XLA packed loop (:func:`life_run_bits_xla`).
+steps) and anything else the compiled-XLA packed loop
+(:func:`life_run_bits_xla`). The tiled kernel holds one halo-extended
+tile window, not the board, so its tiles have a budget of their own
+(``_FUSED_TILE_BUDGET``) under a scoped-VMEM limit it raises to match.
 """
 
 from __future__ import annotations
@@ -50,6 +52,16 @@ from jax.experimental.pallas import tpu as pltpu
 # ~16 MB/core scoped-VMEM budget; measured on v5e: 1.23 MB packed (3200²)
 # compiles, 1.47 MB (3500²) is rejected by Mosaic.
 _PACKED_VMEM_LIMIT = 5 << 18
+
+# Bytes of the fused tiled kernel's halo-extended tile window (its
+# ``tile_budget_bytes`` default), apart from the resident gate above:
+# the tiled kernel holds one window, not a board, so its tiles grow to
+# what the core's VMEM holds under the scoped limit the kernel raises
+# (``_fused_vmem_limit``; a v5e core has 128 MiB). 17 << 18 (4.25 MiB)
+# is one 136 x 8192-word window: an 8192² board steps full-width row
+# tiles of 128 words, (128+8)/128 = 1.0625 of its cells a step, where
+# the resident budget allowed the column plan's 1.1953.
+_FUSED_TILE_BUDGET = 17 << 18
 
 
 def n_words(ny: int) -> int:
@@ -370,12 +382,11 @@ def _fused_tiles_kernel(
 
 
 def _fused_tile_words(
-    nw: int, nx: int, tile_budget_bytes: int = _PACKED_VMEM_LIMIT
+    nw: int, nx: int, tile_budget_bytes: int = _FUSED_TILE_BUDGET
 ) -> int:
     """Tile word rows: the largest multiple-of-8 divisor of ``nw`` whose
-    halo-extended window fits the VMEM working-set budget (the same
-    ~11-temporaries headroom the resident kernel is gated by). 0 = no
-    legal split. ``tile_budget_bytes`` exists so tests can force
+    halo-extended window fits the tile budget (``_FUSED_TILE_BUDGET``).
+    0 = no legal split. ``tile_budget_bytes`` exists so tests can force
     multi-tile grids (and their DMA seams) at small shapes."""
     cap = tile_budget_bytes // (4 * nx) - 2 * _FUSE_HALO_WORDS
     best = 0
@@ -403,13 +414,14 @@ _FUSE_HALO_X = 128
 
 
 def _col_tile_plan(
-    nw: int, nxl: int, tile_budget_bytes: int = _PACKED_VMEM_LIMIT
+    nw: int, nxl: int, tile_budget_bytes: int = _FUSED_TILE_BUDGET
 ):
     """Best ``(amplification, tr, cx)`` column-tiling plan for an ext
     carrying ``_FUSE_HALO_X`` borders, or None. Amplification = redundant
     window area per output area = (tr+2H)/tr * (cx+2HX)/cx; wide boards
-    prefer narrower column tiles (taller row tiles fit the VMEM budget),
-    e.g. 16384-wide drops from 2.0x (tr=8 full-width) to ~1.2x."""
+    prefer narrower column tiles (taller row tiles fit the tile budget),
+    e.g. a 16384-wide board steps 1.129x in 128 x 4096 tiles where
+    full-width tiles of 32 words would step 1.25x."""
     best = None
     for cx in range(128, nxl + 1, 128):
         if nxl % cx:
@@ -424,12 +436,58 @@ def _col_tile_plan(
     return best
 
 
+def _fused_tile_grid(
+    nw: int, nxl: int, halo_x: int, tile_budget_bytes: int,
+) -> tuple[int, int]:
+    """``(tr, cx)`` of :func:`make_fused_stepper`'s grid: full-width row
+    tiles (``cx = nxl``) without an x border, the column plan with one.
+    Raises where no split fits the budget."""
+    if halo_x:
+        plan = _col_tile_plan(nw, nxl, tile_budget_bytes)
+        if plan is None:
+            raise ValueError(
+                f"no legal fused tile split for extended shape "
+                f"{(nw, nxl + 2 * halo_x)}; gate callers on "
+                "fused_bits_supported() / plan_sharded_bits()"
+            )
+        return plan[1], plan[2]
+    tr = _fused_tile_words(nw, nxl, tile_budget_bytes)
+    if tr < 8:
+        raise ValueError(
+            f"no legal fused tile split for packed shape {(nw, nxl)}; "
+            "gate callers on fused_bits_supported()"
+        )
+    return tr, nxl
+
+
+def fused_window_cells(
+    nw: int, nxl: int, halo_x: int = 0,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
+) -> int:
+    """Cells one fused step of :func:`make_fused_stepper` computes over
+    its whole grid: programs x 32·(tr + 2H) bit rows x the window's
+    ``cx + 2·halo_x`` columns. Over the ``32·nw x nxl`` cells it writes,
+    that is the tile plan's amplification; host arithmetic only."""
+    tr, cx = _fused_tile_grid(nw, nxl, halo_x, tile_budget_bytes)
+    return ((nw // tr) * (nxl // cx)
+            * 32 * (tr + 2 * _FUSE_HALO_WORDS) * (cx + 2 * halo_x))
+
+
+def _fused_vmem_limit(window_bytes: int) -> int:
+    """Scoped-VMEM limit of one ``life_fused_tiles`` program: its window
+    scratch, the ~11 same-shape temporaries of the step body and a
+    double-buffered output block (at most a window each), 14 windows in
+    all; never below the 16 MiB default, well under a v5e core's 128.
+    For a 4.25 MiB window the v5e compiler asks 34.5 MB."""
+    return max(16 << 20, 14 * window_bytes)
+
+
 def make_fused_stepper(
     nw: int,
     nxl: int,
     *,
     interpret: bool,
-    tile_budget_bytes: int = _PACKED_VMEM_LIMIT,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
     halo_x: int = 0,
     nx_exact: int | None = None,
 ):
@@ -442,36 +500,22 @@ def make_fused_stepper(
     serial boards), which additionally column-tile on a 2-D grid when
     that lowers the redundant-window amplification."""
     h = _FUSE_HALO_WORDS
-    w_ext = nxl + 2 * halo_x
+    assert not (halo_x and nx_exact is not None), (
+        "wrap-patched rolls need the full width")
+    tr, cx = _fused_tile_grid(nw, nxl, halo_x, tile_budget_bytes)
+    window = (tr + 2 * h, cx + 2 * halo_x)
     if halo_x:
-        assert nx_exact is None, "wrap-patched rolls need the full width"
-        plan = _col_tile_plan(nw, nxl, tile_budget_bytes)
-        if plan is None:
-            raise ValueError(
-                f"no legal fused tile split for extended shape "
-                f"{(nw, w_ext)}; gate callers on fused_bits_supported() / "
-                "plan_sharded_bits()"
-            )
-        _, tr, cx = plan
         grid = (nw // tr, nxl // cx)
         kernel = functools.partial(
             _fused_tiles_kernel, tr=tr, hx=halo_x, cx=cx)
         out_block = pl.BlockSpec(
             (tr, cx), lambda i, j: (i, j), memory_space=pltpu.VMEM)
-        scratch_w = cx + 2 * halo_x
     else:
-        tr = _fused_tile_words(nw, nxl, tile_budget_bytes)
-        if tr < 8:
-            raise ValueError(
-                f"no legal fused tile split for packed shape {(nw, nxl)}; "
-                "gate callers on fused_bits_supported()"
-            )
         grid = (nw // tr,)
         kernel = functools.partial(
             _fused_tiles_kernel, tr=tr, nx_exact=nx_exact)
         out_block = pl.BlockSpec(
             (tr, nxl), lambda i: (i, 0), memory_space=pltpu.VMEM)
-        scratch_w = nxl
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -482,9 +526,11 @@ def make_fused_stepper(
         ],
         out_specs=out_block,
         scratch_shapes=[
-            pltpu.VMEM((tr + 2 * h, scratch_w), jnp.uint32),
+            pltpu.VMEM(window, jnp.uint32),
             pltpu.SemaphoreType.DMA(()),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_fused_vmem_limit(4 * window[0] * window[1])),
         interpret=interpret,
         name="life_fused_tiles",
     )
@@ -639,6 +685,7 @@ class BitPlan:
     k_max: int               # fused steps per exchange round
     mode: str                # "window" | "tiled"
     budget: int              # VMEM budget the mode choice was validated at
+    tile_budget: int         # the tiled stepper's tile budget
 
 
 def plan_sharded_bits(
@@ -657,7 +704,14 @@ def plan_sharded_bits(
     then fall back to the unpacked halo/roll impls. Covers the
     reference's per-step ghost exchange (``3-life/life_mpi.c:198-209``)
     amortised ``k_max``-fold for every shape, not just aligned ones.
+
+    ``budget`` decides the frame and the stepper kind; a tiled plan's
+    stepper then tiles at ``_FUSED_TILE_BUDGET``, whose tiles hold at
+    least those ``budget`` allows. A ``budget`` below the resident gate's
+    (tests forcing small shapes) caps the tiles too.
     """
+    tile_budget = (_FUSED_TILE_BUDGET if budget >= _PACKED_VMEM_LIMIT
+                   else budget)
     ny, nx = shape
     if ny < 8 or nx < 8:
         return None
@@ -720,7 +774,7 @@ def plan_sharded_bits(
             frame=(32 * nw_s * py, W * px), pad_y=pad_y, pad_x=pad_x,
             nw_s=nw_s, W=W, h=h, hx=hx, nx_exact=nx_exact,
             k_max=min(32 * h, hx or FUSE_MAX_STEPS, FUSE_MAX_STEPS),
-            mode=mode, budget=budget,
+            mode=mode, budget=budget, tile_budget=tile_budget,
         )
     return None
 
@@ -797,9 +851,9 @@ def life_run_frame_bits(
 def make_plan_stepper(plan: BitPlan, *, interpret: bool = False):
     """``step_call(k, ext) -> (nw_s, W)`` for a :class:`BitPlan`: the
     whole-window VMEM program for small shards, the DMA-tiled kernel for
-    large ones (tiled at the same budget the planner validated the mode
-    choice against). ``ext`` is the ``(nw_s + 2h, W + 2hx)`` halo-extended
-    packed shard the model layer assembles each exchange round."""
+    large ones (tiled at the plan's ``tile_budget``). ``ext`` is the
+    ``(nw_s + 2h, W + 2hx)`` halo-extended packed shard the model layer
+    assembles each exchange round."""
     if plan.mode == "window":
         return make_window_stepper(
             plan.nw_s, plan.W, h=plan.h, halo_x=plan.hx,
@@ -807,9 +861,23 @@ def make_plan_stepper(plan: BitPlan, *, interpret: bool = False):
         )
     return make_fused_stepper(
         plan.nw_s, plan.W, interpret=interpret,
-        tile_budget_bytes=plan.budget,
+        tile_budget_bytes=plan.tile_budget,
         halo_x=plan.hx, nx_exact=plan.nx_exact,
     )
+
+
+def plan_tile_cells(plan: BitPlan) -> dict:
+    """The counters of a stepping span of the plan's tiled stepper, over
+    all ``py·px`` shards: ``window_cells`` (what one fused step computes,
+    :func:`fused_window_cells`), ``frame_cells`` (the padded frame it
+    writes) and ``board_cells``. Empty for a window-mode plan, which runs
+    no ``life_fused_tiles``."""
+    if plan.mode != "tiled":
+        return {}
+    window = fused_window_cells(plan.nw_s, plan.W, plan.hx, plan.tile_budget)
+    return {"window_cells": plan.py * plan.px * window,
+            "frame_cells": plan.frame[0] * plan.frame[1],
+            "board_cells": plan.shape[0] * plan.shape[1]}
 
 
 def plan_overlap_supported(plan: BitPlan) -> bool:
@@ -861,23 +929,40 @@ def make_overlap_steppers(plan: BitPlan, *, interpret: bool = False):
     return interior, edge
 
 
+def serial_fused_halo_x(
+    nw: int, nx: int, tile_budget_bytes: int = _FUSED_TILE_BUDGET
+) -> int:
+    """The serial runner's x border: 0 for full-width row tiles (wrap by
+    lane roll), ``_FUSE_HALO_X`` for column tiles (x-wrap border + 2-D
+    grid), whichever steps the fewer window cells."""
+    tr_full = _fused_tile_words(nw, nx, tile_budget_bytes)
+    amp_full = ((tr_full + 2 * _FUSE_HALO_WORDS) / tr_full if tr_full >= 8
+                else float("inf"))
+    plan = _col_tile_plan(nw, nx, tile_budget_bytes)
+    return _FUSE_HALO_X if plan is not None and plan[0] < amp_full else 0
+
+
+def fused_tile_cells(shape: tuple[int, int]) -> dict:
+    """:func:`plan_tile_cells` of the serial aligned runner
+    (:func:`life_run_fused_bits`) at its default budget: the board is
+    its own frame."""
+    ny, nx = shape
+    nw = ny // 32
+    window = fused_window_cells(nw, nx, serial_fused_halo_x(nw, nx))
+    return {"window_cells": window, "frame_cells": ny * nx,
+            "board_cells": ny * nx}
+
+
 @functools.partial(
     jax.jit, static_argnames=("interpret", "tile_budget_bytes")
 )
 def _run_fused_bits_jit(
     packed, steps, *, interpret: bool,
-    tile_budget_bytes: int = _PACKED_VMEM_LIMIT,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
 ):
     nw, nx = packed.shape
     h = _FUSE_HALO_WORDS
-    # Pick the less-amplified tiling: full-width row tiles (wrap by lane
-    # roll, no x border) vs column tiles (x-wrap border + 2-D grid).
-    tr_full = _fused_tile_words(nw, nx, tile_budget_bytes)
-    amp_full = ((tr_full + 2 * h) / tr_full if tr_full >= 8
-                else float("inf"))
-    plan = _col_tile_plan(nw, nx, tile_budget_bytes)
-    use_cols = plan is not None and plan[0] < amp_full
-    halo_x = _FUSE_HALO_X if use_cols else 0
+    halo_x = serial_fused_halo_x(nw, nx, tile_budget_bytes)
     step_call = make_fused_stepper(
         nw, nx, interpret=interpret, tile_budget_bytes=tile_budget_bytes,
         halo_x=halo_x,
@@ -899,7 +984,7 @@ def _run_fused_bits_jit(
 
 def life_run_fused_bits(
     board: jnp.ndarray, n: int, *, interpret: bool = False,
-    tile_budget_bytes: int = _PACKED_VMEM_LIMIT,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
 ) -> jnp.ndarray:
     """Advance ``n`` steps of a big board with the multi-step-fused tiled
     kernel: each HBM pass DMAs row tiles once (plus a 128-bit-row halo —
@@ -1180,7 +1265,7 @@ def life_run_bits_xla_batch(boards: jnp.ndarray, n: int) -> jnp.ndarray:
 )
 def _run_fused_bits_batch_jit(
     packed, steps, *, interpret: bool,
-    tile_budget_bytes: int = _PACKED_VMEM_LIMIT,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
 ):
     _note_retrace("life_batch_fused")
     # Sequential scan over the stack, ONE compiled program: fused-regime
@@ -1199,7 +1284,7 @@ def _run_fused_bits_batch_jit(
 
 def life_run_fused_bits_batch(
     boards: jnp.ndarray, n: int, *, interpret: bool = False,
-    tile_budget_bytes: int = _PACKED_VMEM_LIMIT,
+    tile_budget_bytes: int = _FUSED_TILE_BUDGET,
 ) -> jnp.ndarray:
     """Advance B stacked ALIGNED big boards via the multi-step-fused tiled
     kernel, all boards in one dispatch (see the scan note in the jit)."""

@@ -201,6 +201,27 @@ def native_path(shape: tuple[int, int], on_tpu: bool = True) -> str:
     return "xla"
 
 
+def native_tile_cells(
+    path: str, shape: tuple[int, int], boards: int = 1
+) -> dict:
+    """The counters of a stepping span whose advance steps ``boards``
+    boards of ``shape`` on the native ``path`` (what :func:`native_path`
+    or :func:`native_path_batch` answered) through ``life_fused_tiles``:
+    ``window_cells`` (what one fused step computes, halo rows and columns
+    included), ``frame_cells`` (the frame it writes) and ``board_cells``.
+    Empty on the paths that run no tiled kernel; host arithmetic only."""
+    from mpi_and_open_mp_tpu.ops import bitlife
+
+    if path == "fused":
+        cells = bitlife.fused_tile_cells(shape)
+    elif path == "frame":
+        cells = bitlife.plan_tile_cells(
+            bitlife.plan_sharded_bits(shape, 1, 1, False, False))
+    else:
+        return {}
+    return {k: boards * v for k, v in cells.items()}
+
+
 def native_path_batch(
     shape: tuple[int, int, int], on_tpu: bool = True,
     allow_bitsliced: bool = True,

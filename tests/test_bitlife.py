@@ -130,6 +130,64 @@ def test_fused_bits_column_tiled_serial(steps):
     assert np.array_equal(got, _oracle(b, steps)), steps
 
 
+def test_fused_bits_full_width_serial():
+    """The mirror of the column-tiled case: a budget 17/5 of one where
+    only column tiles fit (the tile budget over the resident one) lets
+    full-width row tiles win, on a 2-program grid; bit-exact across a
+    pass boundary."""
+    b = _soup(1024, 1024, seed=8)
+    big = 4 * (16 + 2 * bitlife._FUSE_HALO_WORDS) * 1024
+    small = big * 5 // 17
+    assert bitlife.serial_fused_halo_x(32, 1024, small) == bitlife._FUSE_HALO_X
+    assert bitlife.serial_fused_halo_x(32, 1024, big) == 0
+    assert bitlife._fused_tile_grid(32, 1024, 0, big) == (16, 1024)
+    steps = bitlife.FUSE_MAX_STEPS + 2
+    got = np.asarray(bitlife.life_run_fused_bits(
+        jnp.asarray(b), steps, interpret=True, tile_budget_bytes=big))
+    assert np.array_equal(got, _oracle(b, steps))
+
+
+# The plans of the benchmark's fused-tile cells at the default budgets:
+# (tr, cx, window cells per frame cell) of the tiled stepper, and for the
+# 2x2 plans the frame and exchange the resident budget decides.
+CELL_PLANS = {
+    "serial8192": ((8192, 8192), None, (128, 8192, 1.0625)),
+    "cart8192": ((8192, 8192),
+                 dict(frame=(8192, 8192), nw_s=128, h=4, hx=128, k_max=128),
+                 (128, 4096, 1.12890625)),
+    "cart10000": ((10000, 10000),
+                  dict(frame=(10240, 10240), nw_s=160, h=4, hx=128,
+                       k_max=128),
+                  (160, 5120, 1.1025)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANS))
+def test_default_tile_plans(cell):
+    shape, pinned, (tr, cx, amp) = CELL_PLANS[cell]
+    if pinned is None:
+        nw, nx = shape[0] // 32, shape[1]
+        halo_x = bitlife.serial_fused_halo_x(nw, nx)
+        assert halo_x == 0  # full-width row tiles
+        cells = bitlife.fused_tile_cells(shape)
+    else:
+        plan = bitlife.plan_sharded_bits(shape, 2, 2, True, True)
+        # The tile budget moves the tiles only: mode, frame and exchange
+        # are those of the resident budget.
+        resident = bitlife.plan_sharded_bits(
+            shape, 2, 2, True, True, budget=bitlife._PACKED_VMEM_LIMIT)
+        for key, value in dict(pinned, mode="tiled").items():
+            assert getattr(plan, key) == getattr(resident, key) == value, key
+        assert plan.budget == bitlife._PACKED_VMEM_LIMIT
+        assert plan.tile_budget == bitlife._FUSED_TILE_BUDGET
+        nw, nx, halo_x = plan.nw_s, plan.W, plan.hx
+        cells = bitlife.plan_tile_cells(plan)
+    assert bitlife._fused_tile_grid(
+        nw, nx, halo_x, bitlife._FUSED_TILE_BUDGET) == (tr, cx)
+    assert cells["window_cells"] / cells["frame_cells"] == pytest.approx(
+        amp, rel=1e-12)
+
+
 def test_fused_bits_gate():
     assert bitlife.fused_bits_supported((8192, 8192))
     assert bitlife.fused_bits_supported((16384, 16384))

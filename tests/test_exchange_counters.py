@@ -1,7 +1,8 @@
 """The counters on a sharded bitfused advance's stepping spans
-(``LifeSim._exchange_attrs``): cells of the board and of the padded frame
+(``LifeSim._step_attrs``): cells of the board and of the padded frame
 the kernel steps, exchange rounds, and the bytes a chip sends, tied to
-the ``collective-permute``s of the lowered program."""
+the ``collective-permute``s of the lowered program; and the cells the
+fused tiled kernel computes per cell it writes."""
 
 import json
 import re
@@ -119,3 +120,41 @@ def test_other_paths_carry_no_counters(make_board, traced, layout, impl, mesh):
     np.testing.assert_array_equal(sim.run(), oracle_n(board, 20))
     (span,) = spans_of(traced)
     assert not KEYS & set(span["attrs"])
+
+
+def test_tiled_span_counts_the_window(make_board, monkeypatch, traced):
+    """An advance through the fused tiled kernel carries the cells one
+    fused step computes (``window_cells``) beside the frame it writes:
+    1024² on one device, tiled at a budget that gives two full-width
+    row tiles of 16 words, steps 24/16 of its cells."""
+    budget = 4 * (16 + 2 * bitlife._FUSE_HALO_WORDS) * 1024
+    monkeypatch.setattr(bitlife, "plan_sharded_bits",
+                        partial(bitlife.plan_sharded_bits, budget=budget))
+    board = make_board(1024, 1024)
+    sim = LifeSim(config_from_board(board, 130, 0), layout="row",
+                  impl="bitfused", mesh=mesh_lib.make_mesh_1d(1, axis="y"))
+    assert sim._plan.mode == "tiled"
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 130))
+    (span,) = spans_of(traced)
+    attrs = span["attrs"]
+    assert attrs["frame_cells"] == attrs["board_cells"] == 1024 * 1024
+    assert attrs["window_cells"] == bitlife.fused_window_cells(
+        32, 1024, 0, budget)
+    assert attrs["window_cells"] / attrs["frame_cells"] == 1.5
+    assert not {"rounds", "halo_bytes"} & set(attrs)  # no exchange
+
+
+def test_serial_fused_advance_counts_the_window(make_board, monkeypatch):
+    """The one-chip path (``impl="pallas"``) dispatches a 4096² board to
+    the aligned fused runner on the chip: its spans carry that runner's
+    window at the default tile budget (steered onto the chip's branch;
+    nothing runs)."""
+    from mpi_and_open_mp_tpu.ops import pallas_life
+
+    monkeypatch.setattr(pallas_life, "_interpret", lambda: False)
+    sim = LifeSim(config_from_board(make_board(4096, 4096), 10, 0),
+                  layout="serial", impl="pallas")
+    attrs = sim._step_attrs(10)
+    assert attrs == bitlife.fused_tile_cells((4096, 4096))
+    assert attrs["frame_cells"] == 4096 * 4096
+    assert attrs["window_cells"] > attrs["frame_cells"]
