@@ -396,8 +396,8 @@ class LifeSim:
         self._run_id = 0
         self.reset()
         # What a sharded bitfused advance exchanges, and the cells an
-        # advance through the fused tiled kernel steps: its stepping
-        # spans' counters (``_step_attrs``), set by the step builders.
+        # advance through a fused stepper steps: its stepping spans'
+        # counters (``_step_attrs``), set by the step builders.
         self._exchange = None
         self._tile_cells = {}
         self._advance = self._build_advance()
@@ -629,7 +629,7 @@ class LifeSim:
         use_overlap = hp is not None and hp.overlap
         if mesh.size > 1:
             self._exchange = (plan, _round_halo_bytes(plan))
-        self._tile_cells = bitlife.plan_tile_cells(plan)
+        self._tile_cells = bitlife.plan_tile_cells(plan, use_overlap)
         # 1-shard / ineligible geometry keeps the bare mode string (the
         # historical note); capable geometry appends the schedule stamp.
         self.plan_note = (
@@ -1157,11 +1157,12 @@ class LifeSim:
 
     def _step_attrs(self, *advances: int) -> dict:
         """The counters of a stepping span, for ``advance`` calls of these
-        step counts. An advance through the fused tiled kernel
-        (``life_fused_tiles``) carries ``window_cells`` (the cells one
-        fused step computes over all chips and grid programs, halo rows
-        and columns included), ``frame_cells`` (the frame it writes) and
-        ``board_cells`` (``ny * nx``). A sharded bitfused advance carries
+        step counts. An advance through a fused stepper (the tiled
+        ``life_fused_tiles`` or the whole-shard ``life_fused_window``)
+        carries ``window_cells`` (the cells one fused step computes over
+        all chips and grid programs, halo rows and columns included),
+        ``frame_cells`` (the frame it writes) and ``board_cells``
+        (``ny * nx``). A sharded bitfused advance carries
         ``board_cells``, ``frame_cells`` (the padded frame the kernel
         steps), ``rounds`` (exchange rounds, ``k_max`` steps or fewer
         each) and ``halo_bytes`` (what one chip sends over ``ppermute``

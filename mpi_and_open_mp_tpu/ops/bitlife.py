@@ -866,15 +866,20 @@ def make_plan_stepper(plan: BitPlan, *, interpret: bool = False):
     )
 
 
-def plan_tile_cells(plan: BitPlan) -> dict:
-    """The counters of a stepping span of the plan's tiled stepper, over
-    all ``py·px`` shards: ``window_cells`` (what one fused step computes,
-    :func:`fused_window_cells`), ``frame_cells`` (the padded frame it
-    writes) and ``board_cells``. Empty for a window-mode plan, which runs
-    no ``life_fused_tiles``."""
-    if plan.mode != "tiled":
-        return {}
-    window = fused_window_cells(plan.nw_s, plan.W, plan.hx, plan.tile_budget)
+def plan_tile_cells(plan: BitPlan, overlap: bool = False) -> dict:
+    """The counters of a stepping span of the plan's stepper, over all
+    ``py·px`` shards: ``window_cells`` (what one fused step computes),
+    ``frame_cells`` (the padded frame it writes) and ``board_cells``. A
+    tiled plan computes :func:`fused_window_cells`; a window-mode plan
+    its whole halo-extended shard, ``32·(nw_s + 2h)·(W + 2hx)`` cells,
+    or, split for ``overlap`` (:func:`make_overlap_steppers`), the raw
+    shard and two ``3h``-word edge windows."""
+    if plan.mode == "window":
+        words = plan.nw_s + (6 if overlap else 2) * plan.h
+        window = 32 * words * (plan.W + 2 * plan.hx)
+    else:
+        window = fused_window_cells(plan.nw_s, plan.W, plan.hx,
+                                    plan.tile_budget)
     return {"window_cells": plan.py * plan.px * window,
             "frame_cells": plan.frame[0] * plan.frame[1],
             "board_cells": plan.shape[0] * plan.shape[1]}

@@ -206,10 +206,11 @@ def native_tile_cells(
 ) -> dict:
     """The counters of a stepping span whose advance steps ``boards``
     boards of ``shape`` on the native ``path`` (what :func:`native_path`
-    or :func:`native_path_batch` answered) through ``life_fused_tiles``:
+    or :func:`native_path_batch` answered) through a fused stepper (the
+    aligned tiled runner, or the frame runner's plan stepper):
     ``window_cells`` (what one fused step computes, halo rows and columns
     included), ``frame_cells`` (the frame it writes) and ``board_cells``.
-    Empty on the paths that run no tiled kernel; host arithmetic only."""
+    Empty on the paths that run neither; host arithmetic only."""
     from mpi_and_open_mp_tpu.ops import bitlife
 
     if path == "fused":

@@ -2,7 +2,7 @@
 
 Nothing runs: each test lowers and compiles for a chip that is described,
 not attached, and checks that the kernel is in the program
-(``tpu_custom_call``) or, for the sharded cart step, that the halo's
+(``tpu_custom_call``) or, for the sharded cart and row steps, that the halo's
 ``collective-permute`` is, and that the collect's packer holds no
 collective. What the chip's compiler refuses (unaligned
 slices, too much VMEM) fails here at no chip time. A compile that passes
@@ -63,6 +63,17 @@ def mesh_2x2(topo):
                 axis_types=(AxisType.Auto, AxisType.Auto))
 
 
+@pytest.fixture(scope="module")
+def mesh_4x1(topo):
+    """The same four chips as one ring of row strips: the ``y`` axis
+    alone, as ``apps/life.py --layout row --devices 4`` meshes them."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.array(topo.devices).reshape(4), ("y",),
+                axis_types=(AxisType.Auto,))
+
+
 def _kernel_text(fn, shape, sharding) -> str:
     x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
     return jax.jit(fn).lower(x, N).compile().as_text()
@@ -106,6 +117,29 @@ def test_cart_bitfused_step_compiles_on_2x2(mesh_2x2, monkeypatch, shape):
     advance = sim._build_bitfused_advance()
     board = jax.ShapeDtypeStruct(
         plan.frame, jnp.uint8, sharding=NamedSharding(mesh_2x2, P("y", "x")))
+    text = advance.lower(board, N).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+
+
+def test_row_bitfused_window_step_compiles_on_4x1(mesh_4x1, monkeypatch):
+    """The row layout's bitfused program for ``p46gun_big.cfg``'s 500²
+    board over four strips, as ``LifeSim`` builds it on the chip: a
+    512² frame, 4-word strips in window mode (``life_fused_window``)
+    with an exact 500-wide wrap, and the funnel-shifted wrap ghosts of
+    the 12 mirror rows on the ring's ``collective-permute``."""
+    from mpi_and_open_mp_tpu.models.life import LifeSim
+
+    plan = bitlife.plan_sharded_bits((500, 500), 4, 1,
+                                     y_sharded=True, x_sharded=False)
+    assert plan is not None and plan.mode == "window"
+    assert (plan.nx_exact, plan.h, plan.pad_y) == (500, 3, 12)
+    sim = object.__new__(LifeSim)
+    sim.layout, sim.mesh, sim._plan = "row", mesh_4x1, plan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    advance = sim._build_bitfused_advance()
+    board = jax.ShapeDtypeStruct(
+        plan.frame, jnp.uint8, sharding=NamedSharding(mesh_4x1, P("y", None)))
     text = advance.lower(board, N).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text

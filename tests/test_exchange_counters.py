@@ -2,7 +2,7 @@
 (``LifeSim._step_attrs``): cells of the board and of the padded frame
 the kernel steps, exchange rounds, and the bytes a chip sends, tied to
 the ``collective-permute``s of the lowered program; and the cells the
-fused tiled kernel computes per cell it writes."""
+fused steppers (tiled and whole-window) compute per cell they write."""
 
 import json
 import re
@@ -113,13 +113,73 @@ def test_snapshot_chunk_counts_each_advance(make_board, monkeypatch, traced,
     ("cart", "halo", (2, 2)),
 ], ids=["serial", "bitfused_one_device", "cart_halo"])
 def test_other_paths_carry_no_counters(make_board, traced, layout, impl, mesh):
+    """No exchange counters where nothing is exchanged. The one-device
+    bitfused run still steps its window stepper on the CPU (the chip
+    hands such a mesh to the serial kernel), so it counts that window
+    and nothing else; the roll and halo paths count nothing."""
     board = make_board(64, 64)
     sim = LifeSim(config_from_board(board, steps=20, save_steps=0),
                   layout=layout, impl=impl,
                   mesh=mesh_lib.make_mesh_2d(*mesh) if mesh else None)
     np.testing.assert_array_equal(sim.run(), oracle_n(board, 20))
     (span,) = spans_of(traced)
-    assert not KEYS & set(span["attrs"])
+    counted = {k: v for k, v in span["attrs"].items()
+               if k in KEYS | {"window_cells"}}
+    if impl == "bitfused":
+        assert sim._plan.mode == "window"
+        assert counted == bitlife.plan_tile_cells(sim._plan)
+    else:
+        assert counted == {}
+
+
+def row_sim(make_board, shape, steps):
+    """``shape`` as four row strips, as ``apps/life.py --layout row
+    --devices 4`` meshes them."""
+    board = make_board(*shape)
+    sim = LifeSim(config_from_board(board, steps, 0), layout="row",
+                  impl="bitfused", mesh=mesh_lib.make_mesh_1d(4, axis="y"))
+    return sim, board
+
+
+def test_window_span_counts_the_flagship_row_split(make_board, traced):
+    """``p46gun_big.cfg``'s 500² board as four row strips: a 512² frame
+    in window mode, each chip's 4-word strip stepped in a 10-word window
+    (h 3 a side), so a fused step computes 2.5 cells a cell it writes.
+    A 10⁴-step run takes 105 rounds of 96 steps or fewer, and a round
+    sends 4 words × 512 columns each way (h + 1 words, from which the
+    wrap ghosts are funnel-shifted past the 12 mirror rows): what the
+    lowered program's ``collective_permute``s carry."""
+    sim, board = row_sim(make_board, (500, 500), steps=200)
+    plan = sim._plan
+    assert (plan.mode, plan.nw_s, plan.h, plan.pad_y, plan.nx_exact,
+            plan.k_max) == ("window", 4, 3, 12, 500, 96)
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 200))
+    (span,) = spans_of(traced)
+    attrs = span["attrs"]
+    assert attrs["window_cells"] == 4 * 32 * 10 * 512 == 655_360
+    assert attrs["frame_cells"] == 512 * 512 == 262_144
+    assert attrs["board_cells"] == 500 * 500
+    assert attrs["rounds"] == 3
+    assert permute_bytes(sim) == 16_384
+    run = sim._step_attrs(10_000)
+    assert run["rounds"] == 105
+    assert run["halo_bytes"] == 105 * 16_384 == 1_720_320
+    assert run["window_cells"] / run["frame_cells"] == 2.5
+
+
+def test_overlapped_window_span_counts_both_windows(make_board, traced):
+    """An exact-frame row split steps its round as the raw shard plus
+    two 3h-word edge windows while the ghosts fly
+    (``make_overlap_steppers``): 1280x128 on four chips, 10-word strips,
+    h 4, so a fused step computes 10 + 24 words a strip."""
+    sim, board = row_sim(make_board, (1280, 128), steps=100)
+    assert (sim._plan.nw_s, sim._plan.h) == (10, 4)
+    assert sim.plan_note == "window+overlap:packed"
+    np.testing.assert_array_equal(sim.run(), oracle_n(board, 100))
+    (span,) = spans_of(traced)
+    attrs = span["attrs"]
+    assert attrs["window_cells"] == 4 * 32 * (10 + 6 * 4) * 128
+    assert attrs["frame_cells"] == attrs["board_cells"] == 1280 * 128
 
 
 def test_tiled_span_counts_the_window(make_board, monkeypatch, traced):

@@ -109,6 +109,14 @@ def test_parity_bitfused_cart_mesh(make_board, steps):
     np.testing.assert_array_equal(sim.collect(), oracle_n(board, steps))
 
 
+# The plan a benchmark cell runs, pinned where a parity case is one.
+CELL_PLANS = {
+    ((500, 500), "row", (4, 1)): dict(
+        mode="window", frame=(512, 512), nw_s=4, h=3, pad_y=12,
+        nx_exact=500, k_max=96),
+}
+
+
 @pytest.mark.parametrize(
     "shape,layout,mesh_args,steps",
     [
@@ -129,6 +137,10 @@ def test_parity_bitfused_cart_mesh(make_board, steps):
         ((2040, 128), "row", None, 140),   # ny % (32*8) != 0
         ((2048, 120), "row", None, 140),   # nx % 128 != 0 (patched rolls)
         ((1024, 192), "cart", (4, 2), 100),  # 96-col shards, narrow pitch
+        # The flagship as four row strips (the p46gun_big_row4x1 cell):
+        # 4-word strips of a 512x512 frame, window stepper, sub-word
+        # pad_y 12 with h 3, exact 500-wide wrap; 200 steps, 3 rounds.
+        ((500, 500), "row", (4, 1), 200),
     ],
 )
 def test_parity_bitfused_unaligned(make_board, shape, layout, mesh_args, steps):
@@ -141,6 +153,8 @@ def test_parity_bitfused_unaligned(make_board, shape, layout, mesh_args, steps):
     cfg = config_from_board(board, steps=steps, save_steps=1000)
     sim = LifeSim(cfg, layout=layout, impl="bitfused", mesh=mesh)
     assert steps > sim._plan.k_max, "steps must cross a fused round"
+    for key, value in CELL_PLANS.get((shape, layout, mesh_args), {}).items():
+        assert getattr(sim._plan, key) == value, key
     sim.step(steps)
     np.testing.assert_array_equal(sim.collect(), oracle_n(board, steps))
 
